@@ -42,7 +42,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_smoke_config
-from repro.kernels import ops
+from repro.kernels.paged_attention import (
+    paged_decode_attention,
+    paged_prefill_attention,
+)
 from repro.kernels.ref import (
     attention_ref,
     dequantize_pages_ref,
@@ -101,17 +104,17 @@ def kernel_error(seed: int = 0) -> dict:
             )[0, 0])
         return jnp.stack(outs)
 
-    # impl="pallas" so the *kernel* is measured (interpret-mode on CPU);
-    # impl="auto" would fall back to the ref path, which IS the oracle
-    got = ops.paged_decode_attention(
-        q, k_pages, v_pages, bt, lengths, impl="pallas")
+    # the Pallas kernels themselves, in interpret mode on the CPU; the
+    # ref path would be the oracle compared against itself
+    got = paged_decode_attention(
+        q, k_pages, v_pages, bt, lengths, interpret=True)
     out["decode_fp32"] = float(jnp.max(jnp.abs(got - dense_decode(
         k_pages, v_pages))))
 
     kq8, ks = quantize_kv_ref(k_pages)
     vq8, vs = quantize_kv_ref(v_pages)
-    got8 = ops.paged_decode_attention(
-        q, kq8, vq8, bt, lengths, k_scales=ks, v_scales=vs, impl="pallas")
+    got8 = paged_decode_attention(
+        q, kq8, vq8, bt, lengths, k_scales=ks, v_scales=vs, interpret=True)
     # oracle for int8 = dense attention over the *dequantized* pools
     out["decode_int8"] = float(jnp.max(jnp.abs(got8 - dense_decode(
         dequantize_pages_ref(kq8, ks), dequantize_pages_ref(vq8, vs)))))
@@ -131,12 +134,12 @@ def kernel_error(seed: int = 0) -> dict:
             kv_len=jnp.array([past + C], jnp.int32),
         )[0]
 
-    got = ops.paged_prefill_attention(
-        qc, k_pages, v_pages, table, past, impl="pallas")
+    got = paged_prefill_attention(
+        qc, k_pages, v_pages, table, past, interpret=True)
     out["prefill_fp32"] = float(jnp.max(jnp.abs(
         got - dense_prefill(k_pages, v_pages))))
-    got8 = ops.paged_prefill_attention(
-        qc, kq8, vq8, table, past, k_scales=ks, v_scales=vs, impl="pallas")
+    got8 = paged_prefill_attention(
+        qc, kq8, vq8, table, past, k_scales=ks, v_scales=vs, interpret=True)
     out["prefill_int8"] = float(jnp.max(jnp.abs(got8 - dense_prefill(
         dequantize_pages_ref(kq8, ks), dequantize_pages_ref(vq8, vs)))))
     return out

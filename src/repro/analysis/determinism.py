@@ -16,6 +16,11 @@ rules flag the constructs that historically break that assumption:
 - ``mutable-default`` — mutable default argument values (``[]``,
   ``{}``, ``set()``, …) shared across calls: state leaks between
   invocations and, with it, run-order dependence.
+- ``builtin-hash`` — calls of the builtin ``hash()``.  ``str`` and
+  ``bytes`` hashes are salted per process (``PYTHONHASHSEED``), so a
+  seed, weight or token derived from one differs in every process; use
+  ``zlib.crc32`` on the encoded value.  Calls inside a ``__hash__``
+  method, which only serve in-process lookups, are not flagged.
 
 Only syntactically-evident cases are flagged (no type inference): the
 lint is meant to stay zero-noise so the repo can be kept suppress-free.
@@ -186,3 +191,36 @@ class MutableDefaultChecker(Checker):
                         "across calls; use None and build per-call",
                     ))
         return out
+
+
+@register
+class BuiltinHashChecker(Checker):
+    """Flag builtin ``hash()`` calls outside ``__hash__`` methods."""
+
+    rules = {
+        "builtin-hash": (
+            "hash() of str/bytes is salted per process; derive seeds and "
+            "ids with zlib.crc32 instead"
+        ),
+    }
+
+    def check(self, src: Source) -> List[Finding]:
+        """Return one finding per builtin ``hash()`` call in ``src``."""
+        exempt = set()
+        for node in ast.walk(src.tree):
+            if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)) and (
+                node.name == "__hash__"
+            ):
+                exempt.update(id(n) for n in ast.walk(node))
+        return [
+            self.finding(
+                src, node, "builtin-hash",
+                "hash() differs between processes (PYTHONHASHSEED); use "
+                "zlib.crc32(value.encode())",
+            )
+            for node in ast.walk(src.tree)
+            if isinstance(node, ast.Call)
+            and isinstance(node.func, ast.Name)
+            and node.func.id == "hash"
+            and id(node) not in exempt
+        ]

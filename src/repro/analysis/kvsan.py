@@ -32,6 +32,9 @@ for context — on:
   strictly-more-urgent (lower priority value) request;
 - **shadow divergence** — :meth:`crosscheck` compares the shadow
   against the allocator's own books (run from ``check_no_leaks``).
+- **non-finite logits** — :meth:`check_logits`: a NaN or infinite
+  logit out of a step means the KV it read (or the weights) are
+  corrupt, e.g. a page read after its buffer was donated away.
 
 The sanitizer only *observes*: a clean run with ``sanitize=True`` is
 byte-identical to ``sanitize=False`` (asserted by the mutation suite in
@@ -375,6 +378,20 @@ class KVSanitizer:
                 )
 
     # -- cross-validation ----------------------------------------------------
+    def check_logits(self, rows: Sequence[int], finite: Iterable[bool]) -> None:
+        """Fail when any row's step produced a non-finite logit.
+
+        Parameters
+        ----------
+        rows : sequence of int
+            Sequence rows of the step, in output order.
+        finite : iterable of bool
+            Per row: whether all of its logits were finite.
+        """
+        bad = [r for r, ok in zip(rows, finite) if not ok]
+        if bad:
+            self._fail(f"non-finite logits out of the step for rows {bad}")
+
     def crosscheck(self, allocator) -> None:
         """Compare the shadow against the allocator's own books.
 

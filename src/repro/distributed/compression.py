@@ -74,19 +74,18 @@ def compressed_grad_allreduce(grads: Any, mesh, axis: str = "pod"):
     Used by the multi-pod trainer to sync pod-local gradient averages
     across pods at int8 wire width.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     if mesh is None or axis not in mesh.shape or mesh.shape[axis] == 1:
         return grads
 
     def one(g):
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda a: compressed_psum(a, axis),
             mesh=mesh,
             in_specs=P(),
             out_specs=P(),
-            check_rep=False,
+            check_vma=False,
         )
         return fn(g)
 

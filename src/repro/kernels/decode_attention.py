@@ -93,10 +93,8 @@ def decode_attention(
     lengths: jax.Array,      # (B,) int32
     scale: Optional[float] = None,
     block_k: int = 512,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
 ) -> jax.Array:
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     B, H, hd = q.shape
     _, S, K, hd_v = (
         k_cache.shape[0], k_cache.shape[1], k_cache.shape[2], v_cache.shape[3]

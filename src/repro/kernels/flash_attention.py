@@ -120,11 +120,9 @@ def flash_attention(
     kv_len: Optional[jax.Array] = None,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
 ) -> jax.Array:
     """Pallas flash attention with GQA; matches kernels/ref.attention_ref."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     B, Sq, H, hd = q.shape
     _, Sk, K, hd_v = k.shape[0], k.shape[1], k.shape[2], v.shape[3]
     g = H // k.shape[2]
@@ -190,11 +188,6 @@ def _vmem(shape):
 def _compiler_params():
     import jax.experimental.pallas.tpu as pltpu
 
-    try:
-        return pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        )
-    except (AttributeError, TypeError):  # older naming
-        return pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        )
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+    )

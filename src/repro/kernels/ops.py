@@ -3,8 +3,10 @@
 Models call these entry points; ``impl`` selects:
 - "ref"      : pure-jnp oracle (CPU smoke tests, SPMD dry-run — Mosaic
                lowering requires a real TPU backend);
-- "pallas"   : pl.pallas_call kernel (TPU target; interpret=True on CPU
-               inside the kernel tests);
+- "pallas"   : pl.pallas_call kernel compiled for the TPU (the kernels
+               run in interpret mode only when a caller passes
+               ``interpret=True`` to them directly, as the kernel tests
+               do on the CPU);
 - "auto"     : pallas on TPU backends, ref elsewhere.
 """
 

@@ -123,12 +123,10 @@ def paged_decode_attention(
     block_tables: jax.Array,  # (B, pages_per_seq) int32 page ids
     lengths: jax.Array,       # (B,) int32 — valid tokens (incl. current)
     scale: Optional[float] = None,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
     k_scales: Optional[jax.Array] = None,  # (P, page_size, K) f32 (int8 pools)
     v_scales: Optional[jax.Array] = None,
 ) -> jax.Array:
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     quantized = k_scales is not None
     B, H, hd = q.shape
     P, page_size, K, hd_v = (
@@ -261,7 +259,7 @@ def paged_prefill_attention(
     block_table: jax.Array,  # (pages_per_seq,) int32 page ids
     past: int,               # prompt tokens already prefilled (chunk offset)
     scale: Optional[float] = None,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
     k_scales: Optional[jax.Array] = None,  # (P, page_size, K) f32 (int8 pools)
     v_scales: Optional[jax.Array] = None,
 ) -> jax.Array:
@@ -272,8 +270,6 @@ def paged_prefill_attention(
     ``ceil((past+C)/page_size)`` context pages named by the block table.
     Returns ``(C, H, hd_v)``.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     quantized = k_scales is not None
     C, H, hd = q.shape
     P, page_size, K, hd_v = (

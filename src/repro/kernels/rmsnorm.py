@@ -29,10 +29,8 @@ def rmsnorm(
     gamma: jax.Array,
     eps: float = 1e-5,
     block_rows: int = 256,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
 ) -> jax.Array:
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     orig_shape = x.shape
     d = x.shape[-1]
     rows = 1

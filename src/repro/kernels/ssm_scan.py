@@ -77,10 +77,8 @@ def ssm_scan(
     h0: Optional[jax.Array] = None,
     chunk: int = 128,
     d_block: int = 512,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     B, T, Dd = x.shape
     N = A.shape[1]
     chunk = min(chunk, T)
@@ -134,12 +132,6 @@ def _vmem(shape):
 def _compiler_params():
     import jax.experimental.pallas.tpu as pltpu
 
-    try:
-        # jax >= 0.5 renamed TPUCompilerParams -> CompilerParams
-        return pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        )
-    except (AttributeError, TypeError):
-        return pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        )
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+    )

@@ -1,9 +1,14 @@
 """Serving launcher: LLMSched-scheduled compound jobs on real engines.
 
 The paper's end-to-end driver: spin up N continuous-batching engine
-replicas with a (smoke) model, train the Bayesian-network profiles from
-history, then run a compound-LLM workload through the uncertainty-aware
+replicas of a model, train the Bayesian-network profiles from history,
+then run a compound-LLM workload through the uncertainty-aware
 scheduler and report average JCT against a chosen baseline.
+
+``--arch`` serves the model's published configuration (random weights
+from ``--seed``); ``--smoke`` serves its reduced configuration, which
+is what CPU runs use.  Paged replica ``i`` runs on device ``i`` (modulo
+the device count).
 
 Replicas share one set of weights (as same-model replicas do in
 production), which is what makes ``--migrate`` lossless: a decoding
@@ -29,24 +34,64 @@ up; the run then reports serving cost, escalations, and
 cost-efficiency.
 
 Usage:
-  PYTHONPATH=src python -m repro.launch.serve --arch stablelm-1.6b \
+  PYTHONPATH=src python -m repro.launch.serve --smoke --arch stablelm-1.6b \
       --mix planning --jobs 12 --scheduler llmsched
-  PYTHONPATH=src python -m repro.launch.serve --engine paged \
+  PYTHONPATH=src python -m repro.launch.serve --smoke --engine paged \
       --replicas 2 --kv-pages 13,49 --migrate
-  PYTHONPATH=src python -m repro.launch.serve --engine paged \
+  PYTHONPATH=src python -m repro.launch.serve --smoke --engine paged \
       --replicas 2 --prefix-cache --shared-prompt 32
+  # published width, on a TPU
+  PYTHONPATH=src python -m repro.launch.serve --engine paged --max-len 512 \
+      --prefix-cache --shared-prompt 160
+
+The entry point keeps JAX's persistent compilation cache in
+``$JAX_COMPILATION_CACHE_DIR`` when that is set, else in
+``<repo>/.jax_cache``.
 """
 
 from __future__ import annotations
 
 import argparse
+import os
+from pathlib import Path
+from typing import Optional, Sequence
 
-from repro.configs import get_smoke_config
-from repro.core import DeterministicGate, LLMSched, ProfileStore, make_baselines
+import jax
+
+from repro.configs import get_config, get_smoke_config
+from repro.core import (
+    DeterministicGate,
+    LLMSched,
+    ProfileStore,
+    RunMetrics,
+    make_baselines,
+)
 from repro.serving import ServeConfig, ServingCluster, build_engines
 
 from repro.sim import generate_traces, generate_workload, get_generators
-from repro.sim.workloads import generate_tiered_workload
+from repro.sim.workloads import GeneratedJob, generate_tiered_workload
+
+REPO_ROOT = Path(__file__).resolve().parents[3]
+
+
+def enable_compile_cache() -> str:
+    """Keep compiled programs in a persistent cache; return its directory.
+
+    When ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it itself and
+    no directory is set here.  Otherwise the cache goes to the fixed
+    ``<repo>/.jax_cache``, so that every process of one checkout finds
+    the programs an earlier one compiled.  Every program is kept, not
+    only those that took over a second (JAX's default): set-up compiles
+    about 170 small programs (weight init, kernel inputs) whose compile
+    time adds up to more than that of the few large step programs.
+    Entry points call this; importing the module does not.
+    """
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(REPO_ROOT / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return path
 
 
 def build_scheduler(name: str, store: ProfileStore, epsilon: float, seed: int,
@@ -72,7 +117,7 @@ def config_from_args(args) -> ServeConfig:
             models=models,
             cascade=args.cascade,
             max_batch=args.max_batch,
-            max_len=96,
+            max_len=args.max_len,
             page_size=args.page_size,
             kv_pages=kv_pages,
             kv_dtype=args.kv_dtype,
@@ -90,10 +135,25 @@ def config_from_args(args) -> ServeConfig:
         raise SystemExit(str(e))
 
 
-def main(argv=None) -> int:
-    """Entry point for ``python -m repro.launch.serve``."""
+def model_config(args):
+    """The served model: published (or, with ``--smoke``, reduced) config.
+
+    ``None`` for a ``--models`` fleet, whose replicas
+    :func:`~repro.serving.build_engines` builds at smoke size itself.
+    """
+    if args.models:
+        if not args.smoke:
+            raise SystemExit("--models builds smoke-size replicas; add --smoke")
+        return None
+    return (get_smoke_config if args.smoke else get_config)(args.arch)
+
+
+def build_parser() -> argparse.ArgumentParser:
+    """The ``python -m repro.launch.serve`` command line."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="stablelm-1.6b")
+    ap.add_argument("--smoke", action="store_true",
+                    help="serve the reduced config (CPU runs)")
     ap.add_argument("--mix", default="planning",
                     choices=["mixed", "predefined", "chain", "planning"])
     ap.add_argument("--jobs", type=int, default=12)
@@ -133,6 +193,8 @@ def main(argv=None) -> int:
                     help="attach a DeterministicGate with this strictness "
                          "in [0,1] to score stage outputs")
     ap.add_argument("--max-batch", type=int, default=4)
+    ap.add_argument("--max-len", type=int, default=96,
+                    help="per-sequence token capacity (prompt + decode)")
     ap.add_argument("--page-size", type=int, default=16)
     ap.add_argument("--regular", type=int, default=4)
     ap.add_argument("--epsilon", type=float, default=0.2)
@@ -146,19 +208,46 @@ def main(argv=None) -> int:
     ap.add_argument("--plan-ahead", type=float, default=30.0,
                     help="LLMSched SLO plan-ahead window W in workload "
                          "seconds")
-    args = ap.parse_args(argv)
+    return ap
 
+
+def workload_from_args(args) -> Sequence[GeneratedJob]:
+    """The seeded compound-job workload the command line describes."""
+    if args.slo:
+        return generate_tiered_workload(
+            args.mix, args.jobs, arrival_rate=0.9, seed=args.seed,
+            tightness=args.slo_tightness,
+        )
+    return generate_workload(args.mix, args.jobs, arrival_rate=0.9,
+                             seed=args.seed)
+
+
+def serve(args, engines: Optional[list] = None,
+          workload: Optional[Sequence[GeneratedJob]] = None) -> RunMetrics:
+    """Serve the command line's workload; return the run's metrics.
+
+    Parameters
+    ----------
+    args : argparse.Namespace
+        Parsed :func:`build_parser` arguments.
+    engines : list, optional
+        A fleet already built from ``args`` (e.g. warmed up so that
+        compilation stays out of the run); built here when omitted.
+    workload : sequence of GeneratedJob, optional
+        The jobs to serve, for a caller that inspects them afterwards;
+        :func:`workload_from_args` when omitted.
+    """
     serve_cfg = config_from_args(args)
 
     gens = get_generators()
     apps = [g.template for g in gens.values()]
     store = ProfileStore().fit(apps, generate_traces(args.mix, 300, seed=7))
 
-    cfg = None if serve_cfg.models else get_smoke_config(args.arch)
-    try:
-        engines = build_engines(cfg, serve_cfg)
-    except ValueError as e:
-        raise SystemExit(str(e))
+    if engines is None:
+        try:
+            engines = build_engines(model_config(args), serve_cfg)
+        except ValueError as e:
+            raise SystemExit(str(e))
     sched = build_scheduler(args.scheduler, store, args.epsilon, args.seed,
                             plan_ahead_s=serve_cfg.plan_ahead_s)
     gate = None
@@ -172,15 +261,16 @@ def main(argv=None) -> int:
     elif serve_cfg.cascade:
         raise SystemExit("--cascade requires --gate-strictness")
     cluster = ServingCluster(sched, engines, serve_cfg, gate=gate)
-    if args.slo:
-        wl = generate_tiered_workload(
-            args.mix, args.jobs, arrival_rate=0.9, seed=args.seed,
-            tightness=serve_cfg.slo_tightness,
-        )
-    else:
-        wl = generate_workload(args.mix, args.jobs, arrival_rate=0.9,
-                               seed=args.seed)
-    res = cluster.run(wl)
+    if workload is None:
+        workload = workload_from_args(args)
+    return cluster.run(workload)
+
+
+def main(argv=None) -> int:
+    """Entry point for ``python -m repro.launch.serve``."""
+    args = build_parser().parse_args(argv)
+    enable_compile_cache()
+    res = serve(args)
     goodput = res.goodput()
     slo_part = (
         "" if goodput is None
@@ -200,7 +290,7 @@ def main(argv=None) -> int:
         )
     print(
         f"[serve] scheduler={args.scheduler} mix={args.mix} "
-        f"replicas={len(engines)} jobs={len(res.jcts)} "
+        f"replicas={config_from_args(args).replicas} jobs={len(res.jcts)} "
         f"avg_jct={res.avg_jct:.2f}s makespan={res.makespan:.1f}s "
         f"tokens={res.tokens_generated} overhead={res.avg_overhead_ms:.2f}ms "
         f"preemptions={res.preemptions} migrations={res.migrations} "

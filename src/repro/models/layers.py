@@ -12,6 +12,7 @@ dispatches to Pallas kernels on TPU and their jnp oracles elsewhere.
 from __future__ import annotations
 
 import math
+import zlib
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -44,7 +45,10 @@ class Maker:
         self._n = 0
 
     def sub(self, name: str) -> "Maker":
-        m = Maker(jax.random.fold_in(self.key, hash(name) % (2 ** 31)), self.dtype)
+        # crc32, not hash(): str hashes are salted per process, and the
+        # same seed must give the same weights in every process
+        salt = zlib.crc32(name.encode()) % (2 ** 31)
+        m = Maker(jax.random.fold_in(self.key, salt), self.dtype)
         self.params[name] = m.params
         self.specs[name] = m.specs
         return m
@@ -336,7 +340,6 @@ def mlp_ws_decode(p: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
     Collective bytes per layer drop from O(|W|/model) to O(B·d) —
     ~40x for llama3-405b decode_32k.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from ..distributed.sharding import active_mesh
@@ -364,12 +367,12 @@ def mlp_ws_decode(p: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
         y = jax.lax.psum(a @ wd, "model")          # (B,1,d_l)
         return y
 
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(), P("data", "model"), P("data", "model"), P("model", "data")),
         out_specs=P(None, None, "data"),
-        check_rep=False,
+        check_vma=False,
     )(x, p["w_gate"], p["w_up"], p["w_down"])
 
 

@@ -306,8 +306,6 @@ def moe_ep(
     mesh=None,
 ) -> jax.Array:
     """Expert-parallel MoE under shard_map; falls back to dense w/o mesh."""
-    from jax.experimental.shard_map import shard_map
-
     from ..distributed.sharding import active_mesh
 
     mesh = mesh or active_mesh()
@@ -335,7 +333,7 @@ def moe_ep(
         body_fn, cfg=cfg, ep_axis="data", ep_size=mesh.shape["data"],
         tp_axis=tp_axis,
     )
-    y2d = shard_map(
+    y2d = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -346,7 +344,7 @@ def moe_ep(
             P("data", tp_axis, None),
         ),
         out_specs=P(batch_axes, None),
-        check_rep=False,
+        check_vma=False,
     )(x2d, p["router"], p["w_gate"], p["w_up"], p["w_down"])
 
     if m.n_shared > 0:

@@ -130,9 +130,9 @@ class ServingCluster:
         With ``shared_prompt_tokens`` set, tasks of one application
         share a deterministic system-prompt prefix (page-alignable, so
         prefix-cache replicas deduplicate it) and differ only in a
-        short stage/index suffix.  Uses ``zlib.crc32`` — not ``hash``
-        — for the shared part so the token stream is stable across
-        processes and runs.
+        short stage/index suffix.  Uses ``zlib.crc32`` — not ``hash``,
+        which is salted per process — so the token stream is stable
+        across processes and runs.
 
         Parameters
         ----------
@@ -146,16 +146,15 @@ class ServingCluster:
         list of int
             Token ids for the engine request.
         """
-        if self.shared_prompt_tokens <= 0:
-            return [1 + (hash(task.stage_name) % 32), 2 + task.index % 7]
+        suffix = [
+            1 + (zlib.crc32(task.stage_name.encode()) % 32),
+            2 + task.index % 7,
+        ]
         base = zlib.crc32(app_name.encode())
         sys_prompt = [
             1 + (base + 31 * k) % 97 for k in range(self.shared_prompt_tokens)
         ]
-        return sys_prompt + [
-            1 + (zlib.crc32(task.stage_name.encode()) % 32),
-            2 + task.index % 7,
-        ]
+        return sys_prompt + suffix
 
     def run(self, workload: Sequence[GeneratedJob]) -> TestbedResult:
         """Serve a compound-job workload to completion.

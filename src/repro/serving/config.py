@@ -156,7 +156,10 @@ def build_engines(model_cfg, cfg: ServeConfig, params=None) -> List:
     Slot engines get per-replica seeds (``cfg.seed + i``); paged
     engines share one set of weights per *model name* (initialised from
     ``cfg.seed`` when ``params`` is not supplied), which is what makes
-    live migration between same-name replicas lossless.
+    live migration between same-name replicas lossless.  Paged replica
+    ``i`` runs on ``jax.devices()[i % n_devices]``: on a host with one
+    chip per replica, every replica has a chip of its own and a copy
+    of the weights there.
 
     With ``cfg.models`` set, the fleet is heterogeneous: replica ``i``
     runs the smoke config of ``cfg.models[i]`` and ``model_cfg`` is
@@ -224,6 +227,7 @@ def build_engines(model_cfg, cfg: ServeConfig, params=None) -> List:
                 )
             return None
 
+        devices = jax.devices()
         return [
             PagedLLMEngine(
                 mc,
@@ -234,6 +238,7 @@ def build_engines(model_cfg, cfg: ServeConfig, params=None) -> List:
                 params=params_by_name[mc.name],
                 prefix_cache=cfg.prefix_cache,
                 kv_dtype=cfg.kv_dtype,
+                device=devices[i % len(devices)],
             )
             for i, mc in enumerate(model_cfgs)
         ]

@@ -47,7 +47,7 @@ import os
 import time
 from collections import deque
 from dataclasses import dataclass
-from typing import Any, Callable, Deque, Dict, List, Optional, Tuple
+from typing import Any, Callable, Deque, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -193,6 +193,11 @@ class PagedLLMEngine(LatencyProfileMixin):
         pool, and migration tickets are validated at export.
         Observation-only — clean runs are byte-identical either way.
         Defaults to the ``REPRO_SANITIZE`` environment variable.
+    device : jax.Device, optional
+        The device this replica runs on: weights, page pools and every
+        per-step input are committed to it, so replicas on different
+        chips never route through a default device.  Defaults to
+        ``jax.devices()[0]``.
     """
 
     def __init__(
@@ -209,6 +214,7 @@ class PagedLLMEngine(LatencyProfileMixin):
         prefix_cache: bool = False,
         sanitize: Optional[bool] = None,
         kv_dtype: Optional[str] = None,
+        device: Optional[jax.Device] = None,
     ) -> None:
         if not supports_paged(cfg):
             raise ValueError(
@@ -237,12 +243,17 @@ class PagedLLMEngine(LatencyProfileMixin):
         self.num_pages = num_pages
         self.greedy = greedy
         self.prefill_chunk = prefill_chunk
-        key = jax.random.key(seed)
-        self.params = params if params is not None else init_params(cfg, key)[0]
+        self.device = device if device is not None else jax.devices()[0]
+        with jax.default_device(self.device):
+            if params is None:
+                params = init_params(cfg, jax.random.key(seed))[0]
+            self.params = self._put(params)
+            self.pools = self._put(
+                init_paged_pools(cfg, num_pages, page_size, kv_dtype)
+            )
 
         self.allocator = PageAllocator(num_pages, page_size, sanitize=sanitize)
         self._san = self.allocator.sanitizer
-        self.pools = init_paged_pools(cfg, num_pages, page_size, kv_dtype)
         self.block_tables = np.full(
             (max_seqs, self.pages_per_seq), TRASH_PAGE, np.int32
         )
@@ -261,18 +272,19 @@ class PagedLLMEngine(LatencyProfileMixin):
         )
         self.prefill_skipped_tokens = 0            # prompt tokens never re-run
         self.cow_copies = 0                        # copy-on-write page copies
+        self.decoded_tokens = 0                    # tokens out of decode steps
         self._admit_seq = 0
         self._row_seq: Dict[int, int] = {}
         self._init_latency()
 
         # donate the pools so each step updates KV in place instead of
-        # copying the whole pool (CPU ignores donation and would warn)
-        self._donate = (1,) if jax.default_backend() != "cpu" else ()
+        # copying the whole pool; every pre-step pool array is dead after
+        # the call, so nothing may keep one across a step
         self._decode = jax.jit(
             lambda p, pools, toks, bt, lens: paged_decode_step(
                 p, cfg, pools, toks, bt, lens
             ),
-            donate_argnums=self._donate,
+            donate_argnums=(1,),
         )
         self._prefill_cache: Dict[int, Callable] = {}
         # copy-on-write page duplication (src/dst traced: one compile)
@@ -280,8 +292,61 @@ class PagedLLMEngine(LatencyProfileMixin):
             lambda blocks, src, dst: jax.tree.map(
                 lambda arr: arr.at[:, dst].set(arr[:, src]), blocks
             ),
-            donate_argnums=(0,) if self._donate else (),
+            donate_argnums=(0,),
         )
+
+    def _put(self, tree):
+        """Commit host values (or a pytree of them) to this replica's device."""
+        return jax.device_put(tree, self.device)
+
+    def warm_up(
+        self, prompt_lens: Sequence[int], cached_tokens: Sequence[int] = (0,)
+    ) -> None:
+        """Compile, and run once, every step program a run will use.
+
+        Covers each decode bucket, the prefill ``(past, chunk)`` shapes
+        of prompts of ``prompt_lens`` tokens whose prefill resumes after
+        each of ``cached_tokens`` prefix-cache hits, and, with a prefix
+        cache, the copy-on-write page copy.  Every call reads and writes
+        only the trash page, so no request, page, counter or latency
+        sample changes.  Call it before a timed window to keep
+        compilation out of the window.
+
+        Parameters
+        ----------
+        prompt_lens : sequence of int
+            Prompt lengths the run will prefill.
+        cached_tokens : sequence of int, optional
+            Prefix-cache hit lengths (page multiples) to resume after.
+        """
+        bt = np.full((self.pages_per_seq,), TRASH_PAGE, np.int32)
+        buckets = {_bucket(n, self.max_seqs) for n in range(1, self.max_seqs + 1)}
+        for b in sorted(buckets):
+            zeros = self._put(np.zeros((b,), np.int32))
+            _, self.pools = self._decode(
+                self.params, self.pools, zeros, self._put(np.tile(bt, (b, 1))),
+                zeros,
+            )
+        shapes = set()
+        for plen in prompt_lens:
+            for cached in cached_tokens:
+                pos = min(cached, plen - 1)
+                while pos < plen:
+                    chunk = self._chunk_at(pos, plen)
+                    shapes.add((pos, chunk))
+                    pos += chunk
+        for past, chunk in sorted(shapes):
+            logits, self.pools = self._prefill_fn(past)(
+                self.params, self.pools,
+                self._put(np.zeros((1, chunk), np.int32)), self._put(bt),
+            )
+            jax.device_get(logits[0, -1])   # the slice _run_prefill takes
+        if self.prefix_index is not None:
+            trash = self._put(np.int32(TRASH_PAGE))
+            self.pools = {
+                "blocks": self._copy_page_jit(self.pools["blocks"], trash, trash)
+            }
+        jax.block_until_ready(self.pools)
 
     # -- admission ----------------------------------------------------------
     @property
@@ -558,7 +623,8 @@ class PagedLLMEngine(LatencyProfileMixin):
         """
         self.pools = {
             "blocks": self._copy_page_jit(
-                self.pools["blocks"], jnp.int32(src), jnp.int32(dst)
+                self.pools["blocks"], self._put(np.int32(src)),
+                self._put(np.int32(dst)),
             )
         }
         if self._san is not None and self.kv_dtype == "int8":
@@ -670,10 +736,14 @@ class PagedLLMEngine(LatencyProfileMixin):
                 lambda p, pools, toks, bt: paged_prefill_chunk(
                     p, self.cfg, pools, toks, bt, past
                 ),
-                donate_argnums=self._donate,
+                donate_argnums=(1,),
             )
             self._prefill_cache[past] = fn
         return fn
+
+    def _chunk_at(self, pos: int, plen: int) -> int:
+        """Length of the prefill chunk starting at prompt position ``pos``."""
+        return min(self.prefill_chunk - pos % self.prefill_chunk, plen - pos)
 
     def _run_prefill(self, budget: int) -> None:
         """Advance prompt processing by up to ``budget`` tokens.
@@ -697,9 +767,7 @@ class PagedLLMEngine(LatencyProfileMixin):
                 continue  # evicted while an earlier row made room (CoW)
             req, pos = self.prefilling[row]
             plen = len(req.prompt)
-            chunk = min(
-                self.prefill_chunk - pos % self.prefill_chunk, plen - pos
-            )
+            chunk = self._chunk_at(pos, plen)
             if chunk > budget:
                 break
             # copy-on-write before touching any shared/indexed page the
@@ -717,8 +785,8 @@ class PagedLLMEngine(LatencyProfileMixin):
                         row, self.seq_pages[row][pi],
                         quantized=self.kv_dtype == "int8",
                     )
-            toks = jnp.asarray([req.prompt[pos : pos + chunk]], jnp.int32)
-            bt = jnp.asarray(self.block_tables[row], jnp.int32)
+            toks = self._put(np.asarray([req.prompt[pos : pos + chunk]], np.int32))
+            bt = self._put(self.block_tables[row])
             logits, self.pools = self._prefill_fn(pos)(
                 self.params, self.pools, toks, bt
             )
@@ -726,7 +794,10 @@ class PagedLLMEngine(LatencyProfileMixin):
             pos += chunk
             budget -= chunk
             if pos == plen:
-                first = int(jnp.argmax(logits[0, -1]))
+                last = np.asarray(jax.device_get(logits[0, -1]))
+                if self._san is not None:
+                    self._san.check_logits([row], [np.isfinite(last).all()])
+                first = int(np.argmax(last))
                 req.out_tokens.append(first)
                 req.started_at = time.perf_counter()
                 self._tokens[row] = first
@@ -858,11 +929,14 @@ class PagedLLMEngine(LatencyProfileMixin):
 
         t0 = time.perf_counter()
         logits, self.pools = self._decode(
-            self.params, self.pools, jnp.asarray(toks), jnp.asarray(bt),
-            jnp.asarray(lens),
+            self.params, self.pools, self._put(toks), self._put(bt),
+            self._put(lens),
         )
         logits = np.asarray(jax.device_get(logits))
         self.record_latency(b, time.perf_counter() - t0)
+        self.decoded_tokens += b
+        if self._san is not None:
+            self._san.check_logits(rows, np.isfinite(logits[:b]).all(axis=-1))
 
         finished: List[Request] = []
         for i, row in enumerate(rows):
@@ -956,7 +1030,7 @@ class PagedLLMEngine(LatencyProfileMixin):
             raise ValueError(f"row {row} is not decoding; cannot export")
         req = self.active.pop(row)
         pages = list(self.seq_pages[row])
-        idx = jnp.asarray(np.asarray(pages, np.int32))
+        idx = self._put(np.asarray(pages, np.int32))
         # every pool leaf travels: K/V payload plus, on int8 engines,
         # the per-page scale pools the payload dequantizes through
         kv: Dict[str, Dict[str, np.ndarray]] = {
@@ -1046,11 +1120,11 @@ class PagedLLMEngine(LatencyProfileMixin):
         if pages is None:
             return False
         self.free_rows.pop(0)
-        idx = jnp.asarray(np.asarray(pages, np.int32))
+        idx = self._put(np.asarray(pages, np.int32))
         blocks = {
             j: {
                 name: arr.at[:, idx].set(
-                    jnp.asarray(ticket.kv[j][name], arr.dtype)
+                    self._put(ticket.kv[j][name]).astype(arr.dtype)
                 )
                 for name, arr in pool.items()
             }
@@ -1097,7 +1171,7 @@ class PagedLLMEngine(LatencyProfileMixin):
         perm = np.arange(self.num_pages)
         for old, new in mapping.items():
             perm[new] = old
-        perm_j = jnp.asarray(perm)
+        perm_j = self._put(perm)
         self.pools = jax.tree.map(
             lambda pool: pool[:, perm_j], self.pools["blocks"], is_leaf=None
         )

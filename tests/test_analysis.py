@@ -96,7 +96,7 @@ def _rules(findings):
 # ---------------------------------------------------------------------------
 def test_rule_catalog_is_complete():
     assert set(rule_catalog()) == {
-        "wall-clock", "unordered-set", "mutable-default",
+        "wall-clock", "unordered-set", "mutable-default", "builtin-hash",
         "seed-missing", "unseeded-rng", "global-rng",
         "hot-loop-import",
     }
@@ -206,6 +206,24 @@ def test_mutable_default_rule():
     )
     assert _rules(found) == ["mutable-default", "mutable-default"]
     assert _lint("def g(a=None, b=(), c=0):\n    return a, b, c\n") == []
+
+
+def test_builtin_hash_rule():
+    assert _rules(_lint("k = fold_in(key, hash(name) % 7)\n")) == [
+        "builtin-hash"
+    ]
+    assert _rules(_lint("toks = [hash(s) for s in names]\n")) == [
+        "builtin-hash"
+    ]
+    assert _lint("import zlib\nk = zlib.crc32(name.encode())\n") == []
+    # a __hash__ method serves in-process lookups only
+    assert _lint(
+        "class K:\n"
+        "    def __hash__(self):\n"
+        "        return hash((self.a, self.b))\n"
+    ) == []
+    # attribute calls named hash are not the builtin
+    assert _lint("h = hashlib.sha256(b).hexdigest()\nx = obj.hash()\n") == []
 
 
 # ---------------------------------------------------------------------------
@@ -663,3 +681,10 @@ def test_invariant_violations_aggregate():
     for name in ("no-running-retraction", "demoted-unplaced",
                  "placement-bounds", "edf-urgent-order"):
         assert name in msg
+
+
+def test_kvsan_flags_nonfinite_logits():
+    san = KVSanitizer(num_pages=4, page_size=2)
+    san.check_logits([0, 3], [True, True])
+    with pytest.raises(KVSanError, match=r"non-finite logits .*\[3\]"):
+        san.check_logits([0, 3], [True, False])

@@ -167,3 +167,34 @@ def test_input_specs_cover_all_cells():
             assert "tokens" in specs
             if shape.kind == "decode":
                 assert "cache" in specs
+
+
+def test_params_identical_across_hash_seeds():
+    # weights derive from the seed alone: str hashes are salted per
+    # process, so a name-keyed sub-seed must not come from hash()
+    import hashlib
+    import os
+    import subprocess
+    import sys
+
+    code = (
+        "import hashlib, jax, numpy as np\n"
+        "from repro.configs import get_smoke_config\n"
+        "from repro.models import init_params\n"
+        "p = init_params(get_smoke_config('stablelm_1_6b'), jax.random.key(0))[0]\n"
+        "h = hashlib.sha256()\n"
+        "for leaf in jax.tree.leaves(p):\n"
+        "    h.update(np.asarray(leaf, np.float32).tobytes())\n"
+        "print(h.hexdigest())\n"
+    )
+    src = os.path.join(os.path.dirname(os.path.dirname(__file__)), "src")
+    sums = []
+    for hash_seed in ("1", "2"):
+        env = dict(os.environ, PYTHONHASHSEED=hash_seed, JAX_PLATFORMS="cpu",
+                   PYTHONPATH=src)
+        r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                           text=True, timeout=300, env=env)
+        assert r.returncode == 0, r.stderr[-2000:]
+        sums.append(r.stdout.strip().splitlines()[-1])
+    assert sums[0] == sums[1]
+    assert hashlib.sha256(b"").hexdigest() not in sums

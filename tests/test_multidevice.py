@@ -62,6 +62,7 @@ def test_sharded_decode_matches_single_device():
         from repro.models import init_params, prefill, decode_step
         from repro.models.zoo import param_shapes
         from repro.distributed import sharding as shd
+        from repro.launch.mesh import make_mesh
 
         cfg = get_smoke_config("internlm2_20b").with_(dtype="float32")
         params, specs = init_params(cfg, jax.random.key(0))
@@ -72,7 +73,7 @@ def test_sharded_decode_matches_single_device():
         lg_ref, _ = decode_step(params, cfg, cache, jnp.argmax(last_ref, -1))
 
         # sharded over (data=4, model=2)
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         with shd.use_mesh(mesh):
             p_sh = shd.tree_shardings(specs, params, mesh)
             params_s = jax.device_put(params, p_sh)
@@ -129,3 +130,41 @@ def test_compressed_grad_allreduce_on_mesh():
         print("COMP-OK", rel)
     """)
     assert "COMP-OK" in out
+
+
+@pytest.mark.slow
+def test_replicas_on_own_devices_migrate_across():
+    out = _run("""
+        import jax
+        from repro.configs import get_smoke_config
+        from repro.serving import Request, ServeConfig, build_engines
+
+        cfg = get_smoke_config("stablelm_1_6b")
+        engines = build_engines(cfg, ServeConfig(
+            engine="paged", replicas=4, max_batch=2, max_len=64))
+        devs = jax.devices()
+        for i, eng in enumerate(engines):
+            held = {d for x in jax.tree.leaves((eng.params, eng.pools))
+                    for d in x.devices()}
+            assert held == {devs[i]}, (i, held)
+
+        def drive(eng, req, n):
+            while len(req.out_tokens) < n and not req.done():
+                eng.step()
+
+        src, dst = engines[0], engines[1]
+        alone = Request(rid=1, prompt=[3, 5], max_new_tokens=12)
+        moved = Request(rid=2, prompt=[3, 5], max_new_tokens=12)
+        assert src.admit(alone)
+        drive(src, alone, 12)
+        assert src.admit(moved)
+        drive(src, moved, 4)
+        assert dst.import_request(src.export_request(src.youngest_active_row()))
+        drive(dst, moved, 12)
+        assert moved.out_tokens == alone.out_tokens, (moved.out_tokens, alone.out_tokens)
+        assert {d for x in jax.tree.leaves(dst.pools) for d in x.devices()} == {devs[1]}
+        src.allocator.check_no_leaks()
+        dst.allocator.check_no_leaks()
+        print("PLACE-OK", dst.decoded_tokens)
+    """)
+    assert "PLACE-OK" in out

@@ -284,3 +284,46 @@ def test_equal_deadline_waiters_drain_in_arrival_order(cfg, params):
     assert seq_of[req_a.rid] < seq_of[req_b.rid], (
         "equal-deadline drain re-admitted the younger arrival first"
     )
+
+
+# ---------------------------------------------------------------------------
+# warm-up and device placement
+# ---------------------------------------------------------------------------
+def test_warm_up_touches_no_request_state(cfg, params):
+    prompts = [[1 + (3 * i + k) % 50 for k in range(18)] for i in range(3)]
+    prompts.append(list(prompts[0][:16]))  # page-aligned full hit: CoW
+
+    def serve(warm):
+        eng = PagedLLMEngine(cfg, max_seqs=4, max_len=48, page_size=8,
+                             params=params, prefix_cache=True)
+        if warm:
+            eng.warm_up([18, 16], cached_tokens=(0, 16))
+            assert eng.allocator.free_pages == eng.num_pages - 1
+            assert eng.prefix_cached_tokens == 0
+            assert (eng.cow_copies, eng.decoded_tokens) == (0, 0)
+            assert eng.latency_profile() is None
+        out = []
+        for i, p in enumerate(prompts):
+            req = Request(rid=i, prompt=p, max_new_tokens=6)
+            assert eng.admit(req)
+            _drain(eng)
+            out.append(req.out_tokens)
+        eng.allocator.check_no_leaks(allow_indexed=True)
+        return out, eng.cow_copies
+
+    warm = serve(warm=True)
+    assert warm == serve(warm=False)
+    assert warm[1] == 1   # the page-aligned hit copied on write
+
+
+def test_engine_state_lives_on_its_device(cfg, params):
+    dev = jax.devices()[-1]
+    eng = PagedLLMEngine(cfg, max_seqs=2, max_len=32, page_size=8,
+                         params=params, device=dev)
+    leaves = jax.tree.leaves((eng.params, eng.pools))
+    assert {d for x in leaves for d in x.devices()} == {dev}
+    req = Request(rid=0, prompt=[3, 4, 5], max_new_tokens=4)
+    assert eng.admit(req)
+    _drain(eng)
+    assert len(req.out_tokens) == 4 and eng.decoded_tokens == 3
+    assert {d for x in jax.tree.leaves(eng.pools) for d in x.devices()} == {dev}

@@ -77,3 +77,59 @@ def test_testbed_cluster_completes_jobs(engine_cfg):
     # steady-state rounds are single-digit ms; the mean is dominated by
     # the first cold-cache rounds)
     assert res.avg_overhead_ms < 150
+
+
+# ---------------------------------------------------------------------------
+# the serve command line
+# ---------------------------------------------------------------------------
+def test_serve_command_line_smoke_run():
+    from repro.launch import serve
+
+    args = serve.build_parser().parse_args([
+        "--smoke", "--engine", "paged", "--mix", "planning", "--jobs", "3",
+        "--max-len", "64", "--prefix-cache", "--shared-prompt", "32",
+    ])
+    assert serve.config_from_args(args).max_len == 64
+    wl = serve.workload_from_args(args)
+    res = serve.serve(args, workload=wl)
+    assert len(res.jcts) == len(wl) == 3
+    assert all(gj.job.done() for gj in wl)
+    assert res.tokens_generated > 0 and res.prefill_saved_tokens > 0
+
+
+def test_serve_command_line_picks_the_model():
+    from repro.launch import serve
+
+    parse = serve.build_parser().parse_args
+    assert serve.model_config(parse([])).d_model == 2048       # published
+    assert serve.model_config(parse(["--smoke"])).d_model == 64
+    assert serve.model_config(parse(["--smoke", "--models", "a,b",
+                                     "--replicas", "2"])) is None
+    with pytest.raises(SystemExit, match="--smoke"):
+        serve.model_config(parse(["--models", "a,b", "--replicas", "2"]))
+
+
+@pytest.mark.parametrize("env_dir", [False, True])
+def test_compile_cache_directory(tmp_path, env_dir):
+    import os
+    import subprocess
+    import sys
+
+    from repro.launch.serve import REPO_ROOT
+
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=str(REPO_ROOT / "src"))
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if env_dir:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path)
+    code = (
+        "import jax\n"
+        "from repro.launch.serve import enable_compile_cache\n"
+        "print(enable_compile_cache(), jax.config.jax_compilation_cache_dir,\n"
+        "      jax.config.jax_persistent_cache_min_compile_time_secs)\n"
+    )
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=120, env=env)
+    assert r.returncode == 0, r.stderr[-2000:]
+    want = str(tmp_path) if env_dir else str(REPO_ROOT / ".jax_cache")
+    assert r.stdout.split() == [want, want, "0.0"]

@@ -28,6 +28,10 @@ from .entropy import (
 )
 
 
+#: The cross-round per-job caches whose lookups and hits are counted.
+CACHES = ("est_remaining", "base_estimates", "job_bounds", "uncertainty")
+
+
 @dataclass
 class JobTrace:
     """One historical execution of an application."""
@@ -72,6 +76,10 @@ class AppProfile:
         self._job_bounds: Dict[Tuple[int, bool], Tuple] = {}
         # job_id -> (version, {stage_name: R})
         self._job_ur: Dict[int, Tuple[int, Dict[str, float]]] = {}
+        # per cache of CACHES: versioned lookups, and those answered
+        # from the cache (an uncertainty call hits when every stage does)
+        self.cache_lookups: Dict[str, int] = dict.fromkeys(CACHES, 0)
+        self.cache_hits: Dict[str, int] = dict.fromkeys(CACHES, 0)
 
     # ------------------------------------------------------------------ fit
     def fit(self, traces: Sequence[JobTrace], max_bins: int = 6,
@@ -228,8 +236,10 @@ class AppProfile:
         """
         key = (job.job_id, bool(use_bn))
         if version is not None:
+            self.cache_lookups["base_estimates"] += 1
             hit = self._job_base.get(key)
             if hit is not None and hit[0] == version:
+                self.cache_hits["base_estimates"] += 1
                 return hit[1], hit[2], hit[3]
         ev = self.evidence_for(job, version) if use_bn else {}
         if self._fitted:
@@ -302,6 +312,7 @@ class AppProfile:
         slot = (job.job_id, bool(use_bn))
         sig = ("ident",) if calibrate is None else calib_key
         if version is not None and sig is not None:
+            self.cache_lookups["est_remaining"] += 1
             hit = self._job_rd.get(slot)
             if (
                 hit is not None
@@ -309,6 +320,7 @@ class AppProfile:
                 and hit[1] == sig
                 and hit[2] == mode
             ):
+                self.cache_hits["est_remaining"] += 1
                 return hit[3]
         done, base, has_running = self._base_estimates(job, use_bn, version)
         est: Dict[str, float] = {}
@@ -353,8 +365,10 @@ class AppProfile:
         """[lo, hi] of the job's remaining-duration distribution (line 5)."""
         slot = (job.job_id, bool(use_bn))
         if version is not None:
+            self.cache_lookups["job_bounds"] += 1
             hit = self._job_bounds.get(slot)
             if hit is not None and hit[0] == version:
+                self.cache_hits["job_bounds"] += 1
                 return hit[1]
         ev = self.evidence_for(job, version) if use_bn else {}
         if self._fitted:
@@ -430,7 +444,9 @@ class AppProfile:
                 vcache = {}
                 self._job_ur[job.job_id] = (version, vcache)
             missing = [n for n in stage_names if n not in vcache]
+            self.cache_lookups["uncertainty"] += 1
             if not missing:
+                self.cache_hits["uncertainty"] += 1
                 return [vcache[n] for n in stage_names]
         else:
             missing = list(dict.fromkeys(stage_names))
@@ -505,3 +521,20 @@ class ProfileStore:
         """Evict a finished job's slots from every profile's caches."""
         for prof in self.profiles.values():
             prof.forget_job(job_id)
+
+    def cache_counts(self) -> Dict[str, Dict[str, int]]:
+        """Lookups and hits of each cache in ``CACHES``, over all profiles.
+
+        Returns
+        -------
+        dict
+            ``{cache: {"lookups": n, "hits": h}}``; counts only grow, so
+            the difference of two snapshots covers the time between.
+        """
+        return {
+            c: {
+                "lookups": sum(p.cache_lookups[c] for p in self.profiles.values()),
+                "hits": sum(p.cache_hits[c] for p in self.profiles.values()),
+            }
+            for c in CACHES
+        }

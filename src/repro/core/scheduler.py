@@ -24,6 +24,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..tracing import span
 from .calibration import LatencyProfile
 from .dag import SLO_TIERS, Job, Stage, StageType, Task
 from .profiler import ProfileStore
@@ -687,61 +688,67 @@ class LLMSched(Scheduler):
 
         # ready stages once per job per round (reused for S_t and S_u;
         # cached across rounds for jobs without new events)
-        ready: Dict[int, List[Stage]] = {
-            j.job_id: self._ready_stages(j) for j in jobs
-        }
+        with span("sched.ready"):
+            ready: Dict[int, List[Stage]] = {
+                j.job_id: self._ready_stages(j) for j in jobs
+            }
 
         # per-job remaining-duration bounds (Algorithm 1 line 5; also the
         # SLO plan-ahead input).  Cached per evidence version, so hoisting
         # the computation above the SRTF sort changes nothing numerically.
-        n = len(jobs)
-        los = np.empty(n, dtype=np.float64)
-        his = np.empty(n, dtype=np.float64)
-        for i, job in enumerate(jobs):
-            p = self.profiles.get(job.app.name)
-            lo, hi = (
-                p.job_bounds(job, use_bn=self.use_bn, version=self._version(job))
-                if p
-                else (0.0, math.inf)
-            )
-            los[i] = lo
-            his[i] = hi
+        with span("sched.bounds"):
+            n = len(jobs)
+            los = np.empty(n, dtype=np.float64)
+            his = np.empty(n, dtype=np.float64)
+            for i, job in enumerate(jobs):
+                p = self.profiles.get(job.app.name)
+                lo, hi = (
+                    p.job_bounds(job, use_bn=self.use_bn, version=self._version(job))
+                    if p
+                    else (0.0, math.inf)
+                )
+                los[i] = lo
+                his[i] = hi
 
         # lines 1-4: S_t — ready stages in SRTF order of their job;
         # SLO-aware deadline ordering reshuffles the *job* order (boost /
         # demote) only when at least one job actually carries an SLO —
         # SLO-less workloads keep the historical order byte-for-byte.
-        j_t = sorted(jobs, key=lambda j: (self.est_rd(j, view), j.arrival_time))
-        if self.slo_aware and any(j.slo is not None for j in jobs):
-            j_t = self._slo_order(j_t, view, dict(zip(
-                (j.job_id for j in jobs), zip(los, his)
-            )))
-        s_t: List[Stage] = []
-        for job in j_t:
-            s_t.extend(ready[job.job_id])
+        with span("sched.srtf"):
+            j_t = sorted(jobs, key=lambda j: (self.est_rd(j, view), j.arrival_time))
+            if self.slo_aware and any(j.slo is not None for j in jobs):
+                j_t = self._slo_order(j_t, view, dict(zip(
+                    (j.job_id for j in jobs), zip(los, his)
+                )))
+            s_t: List[Stage] = []
+            for job in j_t:
+                s_t.extend(ready[job.job_id])
 
         # lines 5-10: S_u — stages by uncertainty reduction within
         # non-overlapping job groups (bounds gathered into numpy arrays)
-        s_u: List[Stage] = []
-        for group in self._group_by_overlap(los, his, list(jobs)):
-            # only genuinely uncertainty-reducing stages are exploration
-            # candidates (paper §IV-B: stages correlated with ≥1 other)
-            scored: List[Tuple[float, Stage]] = []
-            for job in group:
-                rs = ready[job.job_id]
-                if rs:
-                    scored.extend(zip(self._uncert_batch(job, rs), rs))
-            scored = [(r, s) for r, s in scored if r > 0.0]
-            scored.sort(key=lambda t: -t[0])
-            s_u.extend(s for _, s in scored)
+        with span("sched.uncertainty"):
+            s_u: List[Stage] = []
+            for group in self._group_by_overlap(los, his, list(jobs)):
+                # only genuinely uncertainty-reducing stages are exploration
+                # candidates (paper §IV-B: stages correlated with ≥1 other)
+                scored: List[Tuple[float, Stage]] = []
+                for job in group:
+                    rs = ready[job.job_id]
+                    if rs:
+                        scored.extend(zip(self._uncert_batch(job, rs), rs))
+                scored = [(r, s) for r, s in scored if r > 0.0]
+                scored.sort(key=lambda t: -t[0])
+                s_u.extend(s for _, s in scored)
 
         # lines 11-20: ε-greedy merge
-        dec = self._merge(s_t, s_u)
+        with span("sched.merge"):
+            dec = self._merge(s_t, s_u)
 
         # multi-replica placement: duration-bound width as the entropy
         # proxy (same arrays that drove the grouping above)
-        self._app_by_job = {j.job_id: j.app.name for j in jobs}
-        self._place_llm(dec, view, self._job_uncertainty(jobs, los, his))
+        with span("sched.place"):
+            self._app_by_job = {j.job_id: j.app.name for j in jobs}
+            self._place_llm(dec, view, self._job_uncertainty(jobs, los, his))
 
         if self.check_invariants:
             # imported lazily: the analysis package must stay optional

@@ -38,6 +38,7 @@ from ..core.metrics import RunMetrics
 from ..core.scheduler import ClusterView, Decision, Scheduler
 from ..models.zoo import tier_spec
 from ..sim.workloads import GeneratedJob, get_generators, reveal_after_stage
+from ..tracing import span, step
 from .config import ServeConfig
 from .engine import LLMEngine, Request
 from .migration import Rebalancer
@@ -279,7 +280,6 @@ class ServingCluster:
                     rid=rid_counter[0],
                     prompt=prompt,
                     max_new_tokens=n_tok,
-                    submitted_at=now(),
                     priority=(
                         math.inf if slo is None
                         else slo.deadline / self.time_scale
@@ -340,7 +340,8 @@ class ServingCluster:
                         res.quality_by_job[task.job_id] = (
                             res.quality_by_job.get(task.job_id, True) and ok
                         )
-                    finish_task(task)
+                    with span("cluster.finish", rid=req.rid):
+                        finish_task(task)
 
                 req.on_finish = _done
                 t.state = TaskState.RUNNING
@@ -380,43 +381,53 @@ class ServingCluster:
             )
 
         # ------------------------- main loop -------------------------
+        it = 0
         while ai < len(arrivals) or active:
-            t = now()
-            # arrivals
-            while ai < len(arrivals) and arrivals[ai][0] <= t:
-                active.append(arrivals[ai][1])
-                ai += 1
-            # regular completions
-            for e in range(self.n_regular):
-                slot = reg_running[e]
-                if slot is not None and slot[0] <= t:
-                    reg_running[e] = None
-                    finish_task(slot[1])
-            # schedule + dispatch
-            t0 = time.perf_counter()
-            dec = self.scheduler.schedule(active, view())
-            res.sched_overhead_s.append(time.perf_counter() - t0)
-            dispatch(dec)
-            # live migration: relieve KV-starved replicas before stepping
-            if self.migrate and self.rebalancer is not None:
-                res.migrations += self.rebalancer.step()
-            # decode step on each engine (the real compute); paged engines
-            # also need steps to re-admit evicted (requeued) requests
-            stepped = False
-            for eng in self.engines:
-                if eng.batch_size or getattr(eng, "waiting", ()):
-                    eng.step()
-                    stepped = True
-            if not stepped:
-                # idle: wait for next arrival or regular completion
-                nxt = [arrivals[ai][0]] if ai < len(arrivals) else []
-                nxt += [s[0] for s in reg_running if s is not None]
-                if nxt:
-                    time.sleep(max(0.0, min(nxt) - now()) + 1e-4)
-                elif not active:
-                    break
-                else:
-                    time.sleep(1e-3)
+            with step("loop", it):
+                it += 1
+                with span("cluster.events"):
+                    t = now()
+                    # arrivals
+                    while ai < len(arrivals) and arrivals[ai][0] <= t:
+                        active.append(arrivals[ai][1])
+                        ai += 1
+                    # regular completions
+                    for e in range(self.n_regular):
+                        slot = reg_running[e]
+                        if slot is not None and slot[0] <= t:
+                            reg_running[e] = None
+                            finish_task(slot[1])
+                # schedule + dispatch
+                t0 = time.perf_counter()
+                with span("cluster.view"):
+                    v = view()
+                with span("sched.schedule"):
+                    dec = self.scheduler.schedule(active, v)
+                res.sched_overhead_s.append(time.perf_counter() - t0)
+                with span("cluster.dispatch"):
+                    dispatch(dec)
+                # live migration: relieve KV-starved replicas before stepping
+                if self.migrate and self.rebalancer is not None:
+                    with span("cluster.migrate"):
+                        res.migrations += self.rebalancer.step()
+                # decode step on each engine (the real compute); paged
+                # engines also need steps to re-admit evicted requests
+                stepped = False
+                for eng in self.engines:
+                    if eng.batch_size or getattr(eng, "waiting", ()):
+                        eng.step()
+                        stepped = True
+                if not stepped:
+                    # idle: wait for next arrival or regular completion
+                    nxt = [arrivals[ai][0]] if ai < len(arrivals) else []
+                    nxt += [s[0] for s in reg_running if s is not None]
+                    if not nxt and not active:
+                        break
+                    with span("cluster.idle"):
+                        if nxt:
+                            time.sleep(max(0.0, min(nxt) - now()) + 1e-4)
+                        else:
+                            time.sleep(1e-3)
         res.makespan = now()
         res.preemptions = sum(getattr(e, "preemptions", 0) for e in self.engines)
         res.prefill_saved_tokens = sum(

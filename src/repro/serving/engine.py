@@ -35,7 +35,10 @@ class Request:
     max_new_tokens: int
     stop_token: Optional[int] = None
     out_tokens: List[int] = field(default_factory=list)
-    submitted_at: float = 0.0
+    # host stamps, all on time.perf_counter(): the latest placement on
+    # an engine's rows (first placement, re-admission after preemption,
+    # import after migration), the first output token, the finish
+    placed_at: float = -1.0
     started_at: float = -1.0
     finished_at: float = -1.0
     on_finish: Optional[Callable[["Request"], None]] = None

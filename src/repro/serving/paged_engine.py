@@ -66,6 +66,7 @@ from ..models.paged import (
     paged_prefill_chunk,
     supports_paged,
 )
+from ..tracing import span
 from .engine import LatencyProfileMixin, Request
 from .paged_cache import PageAllocator, TRASH_PAGE
 from .prefix_cache import RadixPrefixIndex
@@ -74,6 +75,8 @@ from .prefix_cache import RadixPrefixIndex
 # Fleet-global admission stamp: comparable across engines so migrated
 # requests keep their original age in any waiting queue they land in.
 _ARRIVAL_SEQ = itertools.count()
+# Process-wide engine ids: the ``replica`` stat of an engine's spans.
+_REPLICA_IDS = itertools.count()
 
 
 def _bucket(b: int, cap: int) -> int:
@@ -275,25 +278,25 @@ class PagedLLMEngine(LatencyProfileMixin):
         self.decoded_tokens = 0                    # tokens out of decode steps
         self._admit_seq = 0
         self._row_seq: Dict[int, int] = {}
+        self.replica = next(_REPLICA_IDS)
         self._init_latency()
+
+        # named functions, so the trace's modules read jit_decode_step,
+        # jit_prefill_chunk and jit_copy_page (never a kernel's name: the
+        # chip benchmark finds kernels by substring)
+        def decode_step(p, pools, toks, bt, lens):
+            return paged_decode_step(p, cfg, pools, toks, bt, lens)
+
+        def copy_page(blocks, src, dst):
+            return jax.tree.map(lambda arr: arr.at[:, dst].set(arr[:, src]), blocks)
 
         # donate the pools so each step updates KV in place instead of
         # copying the whole pool; every pre-step pool array is dead after
         # the call, so nothing may keep one across a step
-        self._decode = jax.jit(
-            lambda p, pools, toks, bt, lens: paged_decode_step(
-                p, cfg, pools, toks, bt, lens
-            ),
-            donate_argnums=(1,),
-        )
+        self._decode = jax.jit(decode_step, donate_argnums=(1,))
         self._prefill_cache: Dict[int, Callable] = {}
         # copy-on-write page duplication (src/dst traced: one compile)
-        self._copy_page_jit = jax.jit(
-            lambda blocks, src, dst: jax.tree.map(
-                lambda arr: arr.at[:, dst].set(arr[:, src]), blocks
-            ),
-            donate_argnums=(0,),
-        )
+        self._copy_page_jit = jax.jit(copy_page, donate_argnums=(0,))
 
     def _put(self, tree):
         """Commit host values (or a pytree of them) to this replica's device."""
@@ -515,9 +518,10 @@ class PagedLLMEngine(LatencyProfileMixin):
             after preemption).  The caller keeps the task pending and
             retries later.
         """
-        if self.waiting:  # evicted requests re-enter first
-            return False
-        return self._place(req)
+        with span("engine.admit", rid=req.rid):
+            if self.waiting:  # evicted requests re-enter first
+                return False
+            return self._place(req)
 
     def _place(self, req: Request) -> bool:
         plen = len(req.prompt)
@@ -539,6 +543,7 @@ class PagedLLMEngine(LatencyProfileMixin):
                 self.allocator.free(cached)
             return False
         self.free_rows.pop(0)
+        req.placed_at = time.perf_counter()
         if req.arrival_seq < 0:  # first placement anywhere in the fleet
             req.arrival_seq = next(_ARRIVAL_SEQ)
         if self.prefix_index is not None:
@@ -732,12 +737,12 @@ class PagedLLMEngine(LatencyProfileMixin):
     def _prefill_fn(self, past: int) -> Callable:
         fn = self._prefill_cache.get(past)
         if fn is None:
-            fn = jax.jit(
-                lambda p, pools, toks, bt: paged_prefill_chunk(
-                    p, self.cfg, pools, toks, bt, past
-                ),
-                donate_argnums=(1,),
-            )
+            cfg = self.cfg
+
+            def prefill_chunk(p, pools, toks, bt):
+                return paged_prefill_chunk(p, cfg, pools, toks, bt, past)
+
+            fn = jax.jit(prefill_chunk, donate_argnums=(1,))
             self._prefill_cache[past] = fn
         return fn
 
@@ -785,16 +790,20 @@ class PagedLLMEngine(LatencyProfileMixin):
                         row, self.seq_pages[row][pi],
                         quantized=self.kv_dtype == "int8",
                     )
-            toks = self._put(np.asarray([req.prompt[pos : pos + chunk]], np.int32))
-            bt = self._put(self.block_tables[row])
-            logits, self.pools = self._prefill_fn(pos)(
-                self.params, self.pools, toks, bt
-            )
+            with span("engine.prefill.call", rid=req.rid, past=pos, chunk=chunk):
+                toks = self._put(
+                    np.asarray([req.prompt[pos : pos + chunk]], np.int32)
+                )
+                bt = self._put(self.block_tables[row])
+                logits, self.pools = self._prefill_fn(pos)(
+                    self.params, self.pools, toks, bt
+                )
             req.prefill_tokens += chunk
             pos += chunk
             budget -= chunk
             if pos == plen:
-                last = np.asarray(jax.device_get(logits[0, -1]))
+                with span("engine.prefill.sync", rid=req.rid):
+                    last = np.asarray(jax.device_get(logits[0, -1]))
                 if self._san is not None:
                     self._san.check_logits([row], [np.isfinite(last).all()])
                 first = int(np.argmax(last))
@@ -850,18 +859,41 @@ class PagedLLMEngine(LatencyProfileMixin):
             token, or ``max_len``); their pages are already freed and
             ``on_finish`` callbacks already fired.
         """
-        # deadline-aware re-admission: drain the waiting queue lowest
-        # priority-value first, ties broken by *fleet arrival order*
-        # (``arrival_seq``), NOT deque position — the deque reflects
-        # eviction order (``appendleft``), and after a live migration a
-        # younger-arrival request evicted late sits at the head, so a
-        # positional tie-break would re-admit it ahead of an older
-        # equal-deadline waiter.  With no SLOs anywhere (all priorities
-        # inf) single-engine eviction preserves arrival order, so this
-        # still degenerates to the historical FIFO ``popleft``
-        # byte-for-byte.  Head-of-line blocking on a failed place is
-        # intentional: admitting a lower-priority request past a stuck
-        # urgent one would hand it the very pages the urgent one needs.
+        with span("engine.step", replica=self.replica):
+            if self.waiting and self.free_rows:
+                with span("engine.readmit"):
+                    self._readmit()
+            if self.prefilling:
+                with span("engine.prefill"):
+                    self._run_prefill(self.prefill_chunk)
+            if not self.active:
+                return []
+            # page growth (may evict); iterate oldest-first so eviction of
+            # a younger row cannot starve an older one
+            with span("engine.grow"):
+                for row in sorted(self.active, key=lambda r: self._row_seq[r]):
+                    if row in self.active:  # may have been evicted by a prior grow
+                        self._grow(row)
+            if not self.active:
+                return []
+            return self._decode_rows()
+
+    def _readmit(self) -> None:
+        """Re-place evicted requests from ``waiting`` while rows are free.
+
+        Deadline-aware: the queue drains lowest priority-value first,
+        ties broken by *fleet arrival order* (``arrival_seq``), NOT deque
+        position — the deque reflects eviction order (``appendleft``),
+        and after a live migration a younger-arrival request evicted late
+        sits at the head, so a positional tie-break would re-admit it
+        ahead of an older equal-deadline waiter.  With no SLOs anywhere
+        (all priorities inf) single-engine eviction preserves arrival
+        order, so this still degenerates to the historical FIFO
+        ``popleft`` byte-for-byte.  Head-of-line blocking on a failed
+        place is intentional: admitting a lower-priority request past a
+        stuck urgent one would hand it the very pages the urgent one
+        needs.
+        """
         while self.waiting and self.free_rows:
             req = min(
                 self.waiting,
@@ -878,85 +910,79 @@ class PagedLLMEngine(LatencyProfileMixin):
                     getattr(req, "priority", math.inf),
                     [getattr(r, "priority", math.inf) for r in self.waiting],
                 )
-        if self.prefilling:
-            self._run_prefill(self.prefill_chunk)
-        if not self.active:
-            return []
 
-        # page growth (may evict); iterate oldest-first so eviction of a
-        # younger row cannot starve an older one
-        for row in sorted(self.active, key=lambda r: self._row_seq[r]):
-            if row in self.active:  # may have been evicted by a prior grow
-                self._grow(row)
-        if not self.active:
-            return []
-
+    def _decode_rows(self) -> List[Request]:
+        """Decode one token for every active row; return the finished."""
         rows = sorted(self.active, key=lambda r: self._row_seq[r])
         b = len(rows)
         bucket = _bucket(b, self.max_seqs)
-        idx = rows + [rows[0]] * (bucket - b)   # pad shape; padding masked below
-        toks = np.asarray(self._tokens[idx], np.int32)
-        bt = np.asarray(self.block_tables[idx], np.int32)
-        lens = np.asarray(self.lengths[idx], np.int32)
-        # padding rows: length 0, trash block table — writes land in page 0
-        if bucket > b:
-            toks[b:] = 0
-            bt[b:] = TRASH_PAGE
-            lens[b:] = 0
+        with span("engine.decode.prep", rows=b, bucket=bucket):
+            idx = rows + [rows[0]] * (bucket - b)   # pad shape; masked below
+            toks = np.asarray(self._tokens[idx], np.int32)
+            bt = np.asarray(self.block_tables[idx], np.int32)
+            lens = np.asarray(self.lengths[idx], np.int32)
+            # padding rows: length 0, trash block table — writes land in page 0
+            if bucket > b:
+                toks[b:] = 0
+                bt[b:] = TRASH_PAGE
+                lens[b:] = 0
 
-        if self._san is not None:
-            # the incoming token writes at position lengths[row]: that
-            # page must be exclusively owned, and the whole table must
-            # stay inside the pool before the kernel DMAs from it
-            check_block_table_bounds(
-                bt, lens, self.num_pages, self.page_size, TRASH_PAGE
-            )
-            if self.kv_dtype == "int8":
-                # spot-check one layer's scale pools: a NaN/non-positive
-                # scale would multiply *valid* history, not masked slots
-                pool0 = self.pools["blocks"]["0"]
-                check_scale_pool_finite(
-                    np.asarray(jax.device_get(pool0["k_s"][0])),
-                    np.asarray(jax.device_get(pool0["v_s"][0])),
-                    bt, lens, self.page_size,
+            if self._san is not None:
+                # the incoming token writes at position lengths[row]: that
+                # page must be exclusively owned, and the whole table must
+                # stay inside the pool before the kernel DMAs from it
+                check_block_table_bounds(
+                    bt, lens, self.num_pages, self.page_size, TRASH_PAGE
                 )
-            for row in rows:
-                pi = int(self.lengths[row]) // self.page_size
-                self._san.note_write(
-                    row, self.seq_pages[row][pi],
-                    quantized=self.kv_dtype == "int8",
-                )
+                if self.kv_dtype == "int8":
+                    # spot-check one layer's scale pools: a NaN/non-positive
+                    # scale would multiply *valid* history, not masked slots
+                    pool0 = self.pools["blocks"]["0"]
+                    check_scale_pool_finite(
+                        np.asarray(jax.device_get(pool0["k_s"][0])),
+                        np.asarray(jax.device_get(pool0["v_s"][0])),
+                        bt, lens, self.page_size,
+                    )
+                for row in rows:
+                    pi = int(self.lengths[row]) // self.page_size
+                    self._san.note_write(
+                        row, self.seq_pages[row][pi],
+                        quantized=self.kv_dtype == "int8",
+                    )
 
-        t0 = time.perf_counter()
-        logits, self.pools = self._decode(
-            self.params, self.pools, self._put(toks), self._put(bt),
-            self._put(lens),
-        )
-        logits = np.asarray(jax.device_get(logits))
+            # the latency sample spans the device-put, the program and the
+            # logits copy, as it always has
+            t0 = time.perf_counter()
+            args = (self._put(toks), self._put(bt), self._put(lens))
+        with span("engine.decode.call"):
+            logits, self.pools = self._decode(self.params, self.pools, *args)
+        with span("engine.decode.sync"):
+            logits = np.asarray(jax.device_get(logits))
         self.record_latency(b, time.perf_counter() - t0)
         self.decoded_tokens += b
-        if self._san is not None:
-            self._san.check_logits(rows, np.isfinite(logits[:b]).all(axis=-1))
 
-        finished: List[Request] = []
-        for i, row in enumerate(rows):
-            req = self.active[row]
-            nxt = int(np.argmax(logits[i]))
-            req.out_tokens.append(nxt)
-            self._tokens[row] = nxt
-            self.lengths[row] += 1
-            limit = (
-                len(req.out_tokens) >= req.max_new_tokens
-                or (req.stop_token is not None and nxt == req.stop_token)
-                or int(self.lengths[row]) >= self.max_len - 2
-            )
-            if limit:
-                req.finished_at = time.perf_counter()
-                finished.append(req)
-                del self.active[row]
-                self._release_row(row)
-                if req.on_finish:
-                    req.on_finish(req)
+        with span("engine.sample"):
+            if self._san is not None:
+                self._san.check_logits(rows, np.isfinite(logits[:b]).all(axis=-1))
+            finished: List[Request] = []
+            for i, row in enumerate(rows):
+                req = self.active[row]
+                nxt = int(np.argmax(logits[i]))
+                req.out_tokens.append(nxt)
+                self._tokens[row] = nxt
+                self.lengths[row] += 1
+                limit = (
+                    len(req.out_tokens) >= req.max_new_tokens
+                    or (req.stop_token is not None and nxt == req.stop_token)
+                    or int(self.lengths[row]) >= self.max_len - 2
+                )
+                if limit:
+                    req.finished_at = time.perf_counter()
+                    finished.append(req)
+                    del self.active[row]
+                    self._release_row(row)
+                    if req.on_finish:
+                        req.on_finish(req)
         return finished
 
     # -- live migration -----------------------------------------------------
@@ -1143,6 +1169,7 @@ class PagedLLMEngine(LatencyProfileMixin):
         self.lengths[row] = ticket.length
         self._tokens[row] = ticket.last_token
         self.active[row] = ticket.req
+        ticket.req.placed_at = time.perf_counter()
         self._admit_seq += 1
         self._row_seq[row] = self._admit_seq
         self.migrations_in += 1

@@ -51,6 +51,8 @@ from repro.kernels.ref import (
     dequantize_pages_ref,
     gather_pages,
     quantize_kv_ref,
+    stored_pages,
+    stored_scales,
 )
 from repro.models import init_params
 from repro.serving import PagedLLMEngine, Request
@@ -106,15 +108,19 @@ def kernel_error(seed: int = 0) -> dict:
 
     # the Pallas kernels themselves, in interpret mode on the CPU; the
     # ref path would be the oracle compared against itself
+    # the kernels read the stored layout: one layer, rows of K·hd
+    kp_s, vp_s = stored_pages(k_pages)[None], stored_pages(v_pages)[None]
     got = paged_decode_attention(
-        q, k_pages, v_pages, bt, lengths, interpret=True)
+        q, kp_s, vp_s, bt, lengths, interpret=True)
     out["decode_fp32"] = float(jnp.max(jnp.abs(got - dense_decode(
         k_pages, v_pages))))
 
     kq8, ks = quantize_kv_ref(k_pages)
     vq8, vs = quantize_kv_ref(v_pages)
+    q8 = dict(k_scales=stored_scales(ks)[None], v_scales=stored_scales(vs)[None])
+    kq8_s, vq8_s = stored_pages(kq8)[None], stored_pages(vq8)[None]
     got8 = paged_decode_attention(
-        q, kq8, vq8, bt, lengths, k_scales=ks, v_scales=vs, interpret=True)
+        q, kq8_s, vq8_s, bt, lengths, interpret=True, **q8)
     # oracle for int8 = dense attention over the *dequantized* pools
     out["decode_int8"] = float(jnp.max(jnp.abs(got8 - dense_decode(
         dequantize_pages_ref(kq8, ks), dequantize_pages_ref(vq8, vs)))))
@@ -135,11 +141,11 @@ def kernel_error(seed: int = 0) -> dict:
         )[0]
 
     got = paged_prefill_attention(
-        qc, k_pages, v_pages, table, past, interpret=True)
+        qc, kp_s, vp_s, table, past, interpret=True)
     out["prefill_fp32"] = float(jnp.max(jnp.abs(
         got - dense_prefill(k_pages, v_pages))))
     got8 = paged_prefill_attention(
-        qc, kq8, vq8, table, past, k_scales=ks, v_scales=vs, interpret=True)
+        qc, kq8_s, vq8_s, table, past, interpret=True, **q8)
     out["prefill_int8"] = float(jnp.max(jnp.abs(got8 - dense_prefill(
         dequantize_pages_ref(kq8, ks), dequantize_pages_ref(vq8, vs)))))
     return out

@@ -12,8 +12,11 @@ One chip (no arguments):
 1. device check: the first JAX device must be a TPU; there is no CPU
    fallback.
 2. kernels: the paged decode and paged prefill Pallas kernels at
-   stablelm-1.6b widths (32 heads of 64, 16-token pages), bf16 and int8
-   pools, against the ``kernels/ref.py`` oracles in float32.
+   stablelm-1.6b widths (32 heads of 64, 16-token pages) and at
+   internlm2-20b's (48 heads over 8 of 128), bf16 and int8 pools stored
+   as the model step stores them (stacked over layers, read at layer
+   1), and the in-place scale writer, against the ``kernels/ref.py``
+   oracles in float32.
 3. served run: one paged replica (4 sequences of up to 512 tokens),
    LLMSched over the planning mix, a 160-token shared system prompt and
    the prefix cache.  Every step program is compiled first and timed
@@ -139,53 +142,72 @@ def kernel_phase(seed: int) -> None:
     from repro.kernels.paged_attention import (
         paged_decode_attention,
         paged_prefill_attention,
+        write_scales,
     )
 
     B, npp, past, C = 4, 32, 64, 64
     P = 1 + B * npp
     rng = np.random.default_rng(seed)
-    keys = jax.random.split(jax.random.key(seed), 4)
-    shape = (P, PAGE, N_HEADS, HEAD_DIM)
-    k_pages = jax.random.normal(keys[0], shape, jnp.bfloat16)
-    v_pages = jax.random.normal(keys[1], shape, jnp.bfloat16)
-    q_dec = jax.random.normal(keys[2], (B, N_HEADS, HEAD_DIM), jnp.bfloat16)
-    q_pre = jax.random.normal(keys[3], (C, N_HEADS, HEAD_DIM), jnp.bfloat16)
-    tables = jnp.asarray(
-        1 + rng.permutation(P - 1)[: B * npp].reshape(B, npp), jnp.int32
-    )
-    lengths = jnp.asarray(rng.integers(1, npp * PAGE + 1, B), jnp.int32)
-    kq, ks = ref.quantize_kv_ref(k_pages)
-    vq, vs = ref.quantize_kv_ref(v_pages)
     f32 = lambda x: x.astype(jnp.float32)  # noqa: E731
-
-    cases = {
-        "decode_bf16": (
-            lambda: paged_decode_attention(q_dec, k_pages, v_pages, tables,
-                                           lengths),
-            lambda: ref.paged_decode_attention_ref(
-                f32(q_dec), f32(k_pages), f32(v_pages), tables, lengths),
-        ),
-        "decode_int8": (
-            lambda: paged_decode_attention(q_dec, kq, vq, tables, lengths,
-                                           k_scales=ks, v_scales=vs),
-            lambda: ref.paged_decode_attention_ref(
-                f32(q_dec), kq, vq, tables, lengths, k_scales=ks,
-                v_scales=vs),
-        ),
-        "prefill_bf16": (
-            lambda: paged_prefill_attention(q_pre, k_pages, v_pages,
-                                            tables[0], past),
-            lambda: ref.paged_prefill_attention_ref(
-                f32(q_pre), f32(k_pages), f32(v_pages), tables[0], past),
-        ),
-        "prefill_int8": (
-            lambda: paged_prefill_attention(q_pre, kq, vq, tables[0], past,
-                                            k_scales=ks, v_scales=vs),
-            lambda: ref.paged_prefill_attention_ref(
-                f32(q_pre), kq, vq, tables[0], past, k_scales=ks,
-                v_scales=vs),
-        ),
-    }
+    cases = {}
+    # stablelm-1.6b's MHA, and internlm2-20b's GQA
+    for H, K, hd in ((N_HEADS, N_HEADS, HEAD_DIM), (48, 8, 128)):
+        keys = jax.random.split(jax.random.fold_in(jax.random.key(seed), K), 4)
+        # two layers: the kernels read layer 1, layer 0 holds other pages
+        shape = (2, P, PAGE, K, hd)
+        k_pages = jax.random.normal(keys[0], shape, jnp.bfloat16)
+        v_pages = jax.random.normal(keys[1], shape, jnp.bfloat16)
+        q_dec = jax.random.normal(keys[2], (B, H, hd), jnp.bfloat16)
+        q_pre = jax.random.normal(keys[3], (C, H, hd), jnp.bfloat16)
+        tables = jnp.asarray(
+            1 + rng.permutation(P - 1)[: B * npp].reshape(B, npp), jnp.int32
+        )
+        lengths = jnp.asarray(rng.integers(1, npp * PAGE + 1, B), jnp.int32)
+        kq, ks = ref.quantize_kv_ref(k_pages)
+        vq, vs = ref.quantize_kv_ref(v_pages)
+        kp, vp = ref.stored_pages(k_pages), ref.stored_pages(v_pages)
+        kq, vq = ref.stored_pages(kq), ref.stored_pages(vq)
+        sc = dict(k_scales=ref.stored_scales(ks), v_scales=ref.stored_scales(vs))
+        w = f"{H}x{hd}"
+        cases.update({
+            f"decode_bf16_{w}": (
+                lambda q=q_dec, kp=kp, vp=vp, t=tables, n=lengths:
+                    paged_decode_attention(q, kp, vp, t, n, 1),
+                lambda q=q_dec, kp=kp, vp=vp, t=tables, n=lengths:
+                    ref.paged_decode_attention_ref(
+                        f32(q), f32(kp), f32(vp), t, n, 1),
+            ),
+            f"decode_int8_{w}": (
+                lambda q=q_dec, kq=kq, vq=vq, t=tables, n=lengths, sc=sc:
+                    paged_decode_attention(q, kq, vq, t, n, 1, **sc),
+                lambda q=q_dec, kq=kq, vq=vq, t=tables, n=lengths, sc=sc:
+                    ref.paged_decode_attention_ref(f32(q), kq, vq, t, n, 1, **sc),
+            ),
+            f"prefill_bf16_{w}": (
+                lambda q=q_pre, kp=kp, vp=vp, t=tables:
+                    paged_prefill_attention(q, kp, vp, t[0], past, 1),
+                lambda q=q_pre, kp=kp, vp=vp, t=tables:
+                    ref.paged_prefill_attention_ref(
+                        f32(q), f32(kp), f32(vp), t[0], past, 1),
+            ),
+            f"prefill_int8_{w}": (
+                lambda q=q_pre, kq=kq, vq=vq, t=tables, sc=sc:
+                    paged_prefill_attention(q, kq, vq, t[0], past, 1, **sc),
+                lambda q=q_pre, kq=kq, vq=vq, t=tables, sc=sc:
+                    ref.paged_prefill_attention_ref(
+                        f32(q), kq, vq, t[0], past, 1, **sc),
+            ),
+        })
+        # a prefill chunk's scales over consecutive pages, written in place
+        pages = jnp.repeat(tables[0, :4], PAGE // 2)
+        slots = jnp.tile(jnp.arange(PAGE // 2, dtype=jnp.int32) * 2, 4)
+        vals = jax.random.uniform(keys[0], (pages.shape[0], K), jnp.float32)
+        cases[f"write_scales_{w}"] = (
+            lambda s=sc["k_scales"], p=pages, sl=slots, v=vals:
+                write_scales(s, 1, p, sl, v),
+            lambda s=sc["k_scales"], p=pages, sl=slots, v=vals:
+                ref.write_scales_ref(s, 1, p, sl, v),
+        )
     for name, (kernel, oracle) in cases.items():
         got = f32(kernel())
         with jax.default_matmul_precision("highest"):

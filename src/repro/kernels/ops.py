@@ -73,25 +73,28 @@ def paged_decode_attention(
     v_pages: jax.Array,
     block_tables: jax.Array,
     lengths: jax.Array,
+    layer=0,
     scale: Optional[float] = None,
     k_scales: Optional[jax.Array] = None,
     v_scales: Optional[jax.Array] = None,
     impl: str = "auto",
 ) -> jax.Array:
-    """Decode attention over a paged KV pool via per-request block tables.
+    """Decode attention over layer ``layer`` of stacked paged KV pools.
 
-    With ``k_scales``/``v_scales`` the pools are int8 and dequantized
-    per page inside the kernel (oracle: dequantize-then-attend).
+    Pools are stored ``(L, P, page_size, K·hd)`` (see
+    :mod:`repro.kernels.paged_attention`).  With ``k_scales``/``v_scales``
+    the pools are int8 and dequantized per page inside the kernel
+    (oracle: dequantize-then-attend).
     """
     if _pick(impl) == "pallas":
         from .paged_attention import paged_decode_attention as _pda
 
         return _pda(
-            q, k_pages, v_pages, block_tables, lengths, scale=scale,
+            q, k_pages, v_pages, block_tables, lengths, layer, scale=scale,
             k_scales=k_scales, v_scales=v_scales,
         )
     return _ref.paged_decode_attention_ref(
-        q, k_pages, v_pages, block_tables, lengths, scale=scale,
+        q, k_pages, v_pages, block_tables, lengths, layer, scale=scale,
         k_scales=k_scales, v_scales=v_scales,
     )
 
@@ -102,6 +105,7 @@ def paged_prefill_attention(
     v_pages: jax.Array,
     block_table: jax.Array,
     past: int,
+    layer=0,
     scale: Optional[float] = None,
     k_scales: Optional[jax.Array] = None,
     v_scales: Optional[jax.Array] = None,
@@ -109,21 +113,42 @@ def paged_prefill_attention(
 ) -> jax.Array:
     """Fused chunked-prefill attention over one request's block table.
 
-    The chunk's K/V must already be scattered into the pools; queries
-    attend causally to paged history + the in-chunk segment.  With
-    ``k_scales``/``v_scales`` the pools are int8 (see decode).
+    The chunk's K/V must already be scattered into layer ``layer`` of
+    the stacked pools; queries attend causally to paged history + the
+    in-chunk segment.  With ``k_scales``/``v_scales`` the pools are int8
+    (see decode).
     """
     if _pick(impl) == "pallas":
         from .paged_attention import paged_prefill_attention as _ppa
 
         return _ppa(
-            q, k_pages, v_pages, block_table, past, scale=scale,
+            q, k_pages, v_pages, block_table, past, layer, scale=scale,
             k_scales=k_scales, v_scales=v_scales,
         )
     return _ref.paged_prefill_attention_ref(
-        q, k_pages, v_pages, block_table, past, scale=scale,
+        q, k_pages, v_pages, block_table, past, layer, scale=scale,
         k_scales=k_scales, v_scales=v_scales,
     )
+
+
+def write_scales(
+    scales: jax.Array,
+    layer,
+    pages: jax.Array,
+    slots: jax.Array,
+    values: jax.Array,
+    impl: str = "auto",
+) -> jax.Array:
+    """Set tokens' per-head scales in a stored ``(L, P, 1, ps·K)`` scale pool.
+
+    Token ``t``'s ``K`` scales land in slot ``slots[t]`` of page
+    ``pages[t]`` of layer ``layer``; returns the updated pool.
+    """
+    if _pick(impl) == "pallas":
+        from .paged_attention import write_scales as _ws
+
+        return _ws(scales, layer, pages, slots, values)
+    return _ref.write_scales_ref(scales, layer, pages, slots, values)
 
 
 def quantize_kv(x: jax.Array):

@@ -76,8 +76,35 @@ def decode_attention_ref(
 # Paged decode attention: single query token vs a paged KV pool addressed
 # through per-request block tables (vLLM-style PagedAttention)
 # ---------------------------------------------------------------------------
+def stored_pages(pages: jax.Array) -> jax.Array:
+    """Pages ``(..., P, page_size, K, hd)`` -> stored ``(..., P, page_size, K·hd)``.
+
+    A stored page is ``page_size`` rows of the K/V projection's width;
+    the paged kernels and the model step read and write this layout.
+    """
+    return pages.reshape(*pages.shape[:-2], -1)
+
+
+def page_view(pool: jax.Array, n_kv_heads: int) -> jax.Array:
+    """Inverse of :func:`stored_pages`: ``(..., P, page_size, K, hd)``."""
+    return pool.reshape(*pool.shape[:-1], n_kv_heads, -1)
+
+
+def stored_scales(scales: jax.Array) -> jax.Array:
+    """``(..., P, page_size, K)`` scales -> the stored ``(..., P, 1, page_size·K)``.
+
+    A page's scales are one slot-major lane row.
+    """
+    return scales.reshape(*scales.shape[:-2], 1, -1)
+
+
+def scale_view(scales: jax.Array, n_kv_heads: int) -> jax.Array:
+    """Inverse of :func:`stored_scales`: ``(..., P, page_size, K)``."""
+    return scales.reshape(*scales.shape[:-2], -1, n_kv_heads)
+
+
 def gather_pages(
-    pages: jax.Array,         # (P, page_size, K, hd) physical page pool
+    pages: jax.Array,         # (P, page_size, K, hd) page view of one layer
     block_tables: jax.Array,  # (B, pages_per_seq) int32 page ids
 ) -> jax.Array:
     """Materialize the dense (B, S, K, hd) view a block table describes.
@@ -92,21 +119,28 @@ def gather_pages(
     return flat[tok.reshape(B, npp * ps)]
 
 
+def _layer_pages(pool, scales, layer, n_kv_heads):
+    """One layer of a stored pool as float pages ``(P, page_size, K, hd)``."""
+    pages = page_view(pool[layer], n_kv_heads)
+    if scales is not None:
+        pages = dequantize_pages_ref(pages, scale_view(scales[layer], n_kv_heads))
+    return pages
+
+
 def paged_decode_attention_ref(
     q: jax.Array,             # (B, H, hd)
-    k_pages: jax.Array,       # (P, page_size, K, hd)
-    v_pages: jax.Array,       # (P, page_size, K, hd_v)
+    k_pages: jax.Array,       # (L, P, page_size, K·hd) stored pools
+    v_pages: jax.Array,       # (L, P, page_size, K·hd_v)
     block_tables: jax.Array,  # (B, pages_per_seq) int32
     lengths: jax.Array,       # (B,) int32 — valid tokens (incl. current)
+    layer=0,                  # which of the L pools
     scale: Optional[float] = None,
-    k_scales: Optional[jax.Array] = None,  # (P, page_size, K) f32
+    k_scales: Optional[jax.Array] = None,  # (L, P, 1, page_size·K) f32
     v_scales: Optional[jax.Array] = None,
 ) -> jax.Array:
-    if k_scales is not None:
-        k_pages = dequantize_pages_ref(k_pages, k_scales)
-        v_pages = dequantize_pages_ref(v_pages, v_scales)
-    k_dense = gather_pages(k_pages, block_tables)
-    v_dense = gather_pages(v_pages, block_tables)
+    K = k_pages.shape[-1] // q.shape[-1]
+    k_dense = gather_pages(_layer_pages(k_pages, k_scales, layer, K), block_tables)
+    v_dense = gather_pages(_layer_pages(v_pages, v_scales, layer, K), block_tables)
     return decode_attention_ref(q, k_dense, v_dense, lengths, scale=scale)
 
 
@@ -117,24 +151,23 @@ def paged_decode_attention_ref(
 # ---------------------------------------------------------------------------
 def paged_prefill_attention_ref(
     q: jax.Array,            # (C, H, hd) — one request's chunk queries
-    k_pages: jax.Array,      # (P, page_size, K, hd)
-    v_pages: jax.Array,      # (P, page_size, K, hd_v)
+    k_pages: jax.Array,      # (L, P, page_size, K·hd) stored pools
+    v_pages: jax.Array,      # (L, P, page_size, K·hd_v)
     block_table: jax.Array,  # (pages_per_seq,) int32
     past: int,               # prompt tokens already prefilled (chunk offset)
+    layer=0,                 # which of the L pools
     scale: Optional[float] = None,
-    k_scales: Optional[jax.Array] = None,  # (P, page_size, K) f32
+    k_scales: Optional[jax.Array] = None,  # (L, P, 1, page_size·K) f32
     v_scales: Optional[jax.Array] = None,
 ) -> jax.Array:
-    if k_scales is not None:
-        k_pages = dequantize_pages_ref(k_pages, k_scales)
-        v_pages = dequantize_pages_ref(v_pages, v_scales)
+    K = k_pages.shape[-1] // q.shape[-1]
     C = q.shape[0]
-    ps = k_pages.shape[1]
+    ps = k_pages.shape[2]
     ctx = past + C
     n_ctx_pages = -(-ctx // ps)
     bt = block_table[None, :n_ctx_pages]
-    k_ctx = gather_pages(k_pages, bt)            # (1, n_ctx_pages*ps, K, hd)
-    v_ctx = gather_pages(v_pages, bt)
+    k_ctx = gather_pages(_layer_pages(k_pages, k_scales, layer, K), bt)
+    v_ctx = gather_pages(_layer_pages(v_pages, v_scales, layer, K), bt)
     kv_len = jnp.array([ctx], jnp.int32)
     out = attention_ref(
         q[None], k_ctx, v_ctx, causal=True, scale=scale,
@@ -165,9 +198,29 @@ def quantize_kv_ref(x: jax.Array):
     return q.astype(jnp.int8), scale.astype(jnp.float32)
 
 
+def write_scales_ref(
+    scales: jax.Array,   # (L, P, 1, page_size·K) f32 stored scale pool
+    layer,
+    pages: jax.Array,    # (T,) int32
+    slots: jax.Array,    # (T,) int32
+    values: jax.Array,   # (T, K) f32
+) -> jax.Array:
+    """Scatter T tokens' per-head scales into a stored scale pool."""
+    T, K = values.shape
+    at = jnp.stack([jnp.broadcast_to(layer, (T,)), pages,
+                    jnp.zeros_like(pages), slots * K], axis=1)
+    dims = jax.lax.ScatterDimensionNumbers(
+        update_window_dims=(1,), inserted_window_dims=(0, 1, 2),
+        scatter_dims_to_operand_dims=(0, 1, 2, 3),
+    )
+    return jax.lax.scatter(
+        scales, at.astype(jnp.int32), values.astype(scales.dtype), dims
+    )
+
+
 def dequantize_pages_ref(
-    pages: jax.Array,    # (P, page_size, K, hd) int8
-    scales: jax.Array,   # (P, page_size, K) f32
+    pages: jax.Array,    # (P, page_size, K, hd) int8 page view
+    scales: jax.Array,   # (P, page_size, K) f32 scale view
 ) -> jax.Array:
     """Reconstruct float32 pages from an int8 pool and its scale pool."""
     return pages.astype(jnp.float32) * scales[..., None]

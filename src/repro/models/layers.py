@@ -87,15 +87,26 @@ def rmsnorm(gamma: jax.Array, x: jax.Array, eps: float) -> jax.Array:
 # RoPE
 # ---------------------------------------------------------------------------
 def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (B, S, H, hd); positions: (B, S) int32."""
-    hd = x.shape[-1]
+    """x: (B, S, H, hd); positions: (B, S) int32.
+
+    Rotates each head's halves, ``(x1, x2) -> (x1·cos - x2·sin,
+    x2·cos + x1·sin)``, on the flat ``(B, S, H·hd)`` view: on a TPU the
+    per-head form makes the compiler emit the q/k projections head-major
+    and copy each layer's projection weights into that layout first.
+    """
+    B, S, H, hd = x.shape
     half = hd // 2
     freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
     ang = positions[..., None].astype(jnp.float32) * freqs  # (B,S,half)
-    cos, sin = jnp.cos(ang)[:, :, None, :], jnp.sin(ang)[:, :, None, :]
-    x1, x2 = x[..., :half], x[..., half:]
-    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
-    return out.astype(x.dtype)
+    sin = jnp.sin(ang)
+    cos = jnp.tile(jnp.cos(ang), (1, 1, 2 * H))            # (B,S,H·hd)
+    sin = jnp.tile(jnp.concatenate([-sin, sin], axis=-1), (1, 1, H))
+    xf = x.reshape(B, S, H * hd)
+    first = jnp.arange(H * hd) % hd < half
+    # each lane's partner in its own head: x2 for the first half, x1 after
+    partner = jnp.where(first, jnp.roll(xf, -half, axis=-1),
+                        jnp.roll(xf, half, axis=-1))
+    return (xf * cos + partner * sin).astype(x.dtype).reshape(x.shape)
 
 
 # ---------------------------------------------------------------------------

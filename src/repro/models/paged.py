@@ -1,11 +1,17 @@
 """Paged KV-cache model path (vLLM-style) for the serving engine.
 
 Instead of one dense ``(B, S_max, K, hd)`` slot cache per attention layer,
-K/V live in a shared pool of fixed-size pages, ``(P, page_size, K, hd)``,
-and each request owns a *block table* mapping logical token positions to
-physical pages.  The same block table is shared by every layer (each
-layer has its own physical pool, like vLLM), so allocation is a single
-host-side decision per page.
+K/V live in a shared pool of fixed-size pages, and each request owns a
+*block table* mapping logical token positions to physical pages.  The
+same block table is shared by every layer (each layer has its own
+physical pool, like vLLM), so allocation is a single host-side decision
+per page.
+
+A step program holds the pools where they are: the stacked pools ride
+the layer loop's carry, each layer scatters only its new tokens' K/V
+slots into them in place, and the kernels read pages straight from the
+stacked pools by (layer, page) — no layer's pool is sliced out, copied
+or relaid, and the donated input buffers are the outputs.
 
 Three entry points, mirroring ``transformer.py``'s cache contract:
 
@@ -74,12 +80,17 @@ def init_paged_pools(
 ) -> Pools:
     """Per-pattern-position page pools, stacked over superblocks.
 
-    Shape mirrors ``init_cache``'s ``blocks`` tree: pools["blocks"][j] is
-    ``{"k","v": (n_sb, P, page_size, K, hd)}``.  With ``kv_dtype="int8"``
-    the K/V leaves are int8 and per-page scale pools ride alongside:
-    ``{"k_s","v_s": (n_sb, P, page_size, K) float32}``, initialised to a
-    neutral scale of 1 (never-written slots dequantize to finite values
-    the kernels' masking then discards).
+    ``pools["blocks"][j]`` is ``{"k","v": (n_sb, P, page_size, K·hd)}``:
+    the page axis is axis 1, and a page is ``page_size`` rows of the
+    K/V projection's own width (head ``h`` of slot ``t`` in columns
+    ``h·hd .. (h+1)·hd`` of row ``t``), the layout the paged kernels
+    read in place and whose default TPU layout is row-major.  With
+    ``kv_dtype="int8"`` the K/V leaves are int8 and per-page scale pools
+    ride alongside: ``{"k_s","v_s": (n_sb, P, 1, page_size·K) float32}``,
+    one scale per token slot per kv head, a page's ``(page_size, K)``
+    scales flattened slot-major into one row; initialised to a neutral
+    scale of 1 (never-written slots dequantize to finite values the
+    kernels' masking then discards).
     """
     if not supports_paged(cfg):
         raise ValueError(
@@ -90,15 +101,16 @@ def init_paged_pools(
         raise ValueError(f"kv_dtype must be one of {KV_DTYPES}, got {kv_dtype!r}")
     _, pat, n_sb = _scan_layout(cfg)
     K, hd = cfg.n_kv_heads, cfg.hd
-    kv_shape = (n_sb, num_pages, page_size, K, hd)
+    kv_shape = (n_sb, num_pages, page_size, K * hd)
+    scale_shape = (n_sb, num_pages, 1, page_size * K)
 
     def one_pool():
         if kv_dtype == "int8":
             return {
                 "k": jnp.zeros(kv_shape, jnp.int8),
                 "v": jnp.zeros(kv_shape, jnp.int8),
-                "k_s": jnp.ones(kv_shape[:-1], jnp.float32),
-                "v_s": jnp.ones(kv_shape[:-1], jnp.float32),
+                "k_s": jnp.ones(scale_shape, jnp.float32),
+                "v_s": jnp.ones(scale_shape, jnp.float32),
             }
         return {"k": jnp.zeros(kv_shape, cfg.jdtype),
                 "v": jnp.zeros(kv_shape, cfg.jdtype)}
@@ -106,46 +118,50 @@ def init_paged_pools(
     return {"blocks": {str(j): one_pool() for j in range(pat)}}
 
 
-def _scatter_tokens(
-    pool: jax.Array,       # (P, ps, K, hd)
-    flat_idx: jax.Array,   # (T,) int32 — page*ps + offset per token
-    values: jax.Array,     # (T, K, hd)
-) -> jax.Array:
-    P, ps, K, hd = pool.shape
-    flat = pool.reshape(P * ps, K, hd)
-    flat = flat.at[flat_idx].set(values.astype(flat.dtype))
-    return flat.reshape(P, ps, K, hd)
+def _write_kv(pool: Dict[str, jax.Array], layer, page, slot, k, v):
+    """Scatter one batch of K/V tokens into ``layer`` of the stacked pools.
 
-
-def _scatter_scales(
-    pool: jax.Array,       # (P, ps, K) f32 scale pool
-    flat_idx: jax.Array,   # (T,) int32
-    scales: jax.Array,     # (T, K)
-) -> jax.Array:
-    P, ps, K = pool.shape
-    flat = pool.reshape(P * ps, K)
-    flat = flat.at[flat_idx].set(scales.astype(flat.dtype))
-    return flat.reshape(P, ps, K)
-
-
-def _write_kv(pool: Dict[str, jax.Array], flat_idx, k, v):
-    """Scatter one batch of K/V tokens, quantizing when the pool is int8.
-
-    ``k``/``v`` are ``(T, K, hd)``; returns the updated pool dict.
+    ``k``/``v`` are ``(T, K, hd)``; token ``i`` lands in slot
+    ``slot[i]`` of page ``page[i]``, quantized when the pool is int8.
+    Only those slots are written; returns the updated pool dict.
     """
+    T = k.shape[0]
+    out = dict(pool)
     if "k_s" in pool:
-        kq, ks = ops.quantize_kv(k)
-        vq, vs = ops.quantize_kv(v)
-        return {
-            "k": _scatter_tokens(pool["k"], flat_idx, kq),
-            "v": _scatter_tokens(pool["v"], flat_idx, vq),
-            "k_s": _scatter_scales(pool["k_s"], flat_idx, ks),
-            "v_s": _scatter_scales(pool["v_s"], flat_idx, vs),
-        }
-    return {
-        "k": _scatter_tokens(pool["k"], flat_idx, k),
-        "v": _scatter_tokens(pool["v"], flat_idx, v),
-    }
+        k, ks = ops.quantize_kv(k)
+        v, vs = ops.quantize_kv(v)
+        out["k_s"] = ops.write_scales(pool["k_s"], layer, page, slot, ks)
+        out["v_s"] = ops.write_scales(pool["v_s"], layer, page, slot, vs)
+    for name, x in (("k", k), ("v", v)):
+        arr = pool[name]
+        out[name] = arr.at[layer, page, slot].set(
+            x.reshape(T, -1).astype(arr.dtype)
+        )
+    return out
+
+
+def _layer_loop(params: Params, cfg: ModelConfig, pools: Pools, x, layer):
+    """Run every superblock with the stacked pools in the loop's carry.
+
+    ``layer(p, pool, i, j, x) -> (x, pool)`` runs pattern position
+    ``j`` of superblock ``i`` (a traced index).  Carrying the pools,
+    rather than scanning them as per-layer slices, lets each layer write
+    its tokens in place and the kernels read by layer index.
+    """
+    _, pat, n_sb = _scan_layout(cfg)
+
+    def body(carry, xs):
+        x, blocks = carry
+        p, i = xs
+        blocks = dict(blocks)
+        for j in range(pat):
+            x, blocks[str(j)] = layer(p[str(j)], blocks[str(j)], i, j, x)
+        return (x, blocks), None
+
+    (x, blocks), _ = jax.lax.scan(
+        body, (x, pools["blocks"]), (params["blocks"], jnp.arange(n_sb))
+    )
+    return x, {"blocks": blocks}
 
 
 # ---------------------------------------------------------------------------
@@ -161,46 +177,35 @@ def paged_decode_step(
 ) -> Tuple[jax.Array, Pools]:
     """One decode step over paged KV; returns (logits (B, V), pools)."""
     B = tokens.shape[0]
-    _, pat, n_sb = _scan_layout(cfg)
+    _, pat, _ = _scan_layout(cfg)
     ps = pools["blocks"]["0"]["k"].shape[2]
     x = L.embed(params, tokens[:, None]).astype(cfg.jdtype)
 
     rows = jnp.arange(B)
     write_page = block_tables[rows, lengths // ps]          # (B,)
-    write_flat = write_page * ps + lengths % ps             # (B,)
+    write_slot = lengths % ps                               # (B,)
     kinds = [layer_kind(cfg, j) for j in range(pat)]
 
-    def layer(p: Params, pool: Dict[str, jax.Array], j: int, x: jax.Array):
+    def layer(p: Params, pool: Dict[str, jax.Array], i, j: int, x: jax.Array):
         h = L.rmsnorm(p["norm1"], x, cfg.norm_eps)
         q, k, v = L._proj_qkv(p["attn"], cfg, h, h)         # (B,1,·,hd)
         pos = lengths[:, None]
         q = L.rope(q, pos, cfg.rope_theta)
         k = L.rope(k, pos, cfg.rope_theta)
-        new_pool = _write_kv(pool, write_flat, k[:, 0], v[:, 0])
+        pool = _write_kv(pool, i, write_page, write_slot, k[:, 0], v[:, 0])
         out = ops.paged_decode_attention(
-            q[:, 0], new_pool["k"], new_pool["v"], block_tables, lengths + 1,
-            k_scales=new_pool.get("k_s"), v_scales=new_pool.get("v_s"),
+            q[:, 0], pool["k"], pool["v"], block_tables, lengths + 1, i,
+            k_scales=pool.get("k_s"), v_scales=pool.get("v_s"),
         )
         x = x + out.reshape(B, 1, cfg.n_heads * cfg.hd) @ p["attn"]["wo"]
         x = _apply_ffn(p, cfg, kinds[j], x, decoding=True)
-        return x, new_pool
+        return x, pool
 
-    def body(x, xs):
-        new_blk = {}
-        for j in range(pat):
-            p, pool = xs[str(j)]
-            x, new_blk[str(j)] = layer(p, pool, j, x)
-        return x, new_blk
-
-    xs = {
-        str(j): (params["blocks"][str(j)], pools["blocks"][str(j)])
-        for j in range(pat)
-    }
-    x, new_blocks = jax.lax.scan(body, x, xs)
+    x, pools = _layer_loop(params, cfg, pools, x, layer)
 
     x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
     logits = L.unembed(params, x, cfg.tie_embeddings)
-    return logits[:, 0], {"blocks": new_blocks}
+    return logits[:, 0], pools
 
 
 # ---------------------------------------------------------------------------
@@ -224,43 +229,31 @@ def paged_prefill_chunk(
     multiples of the chunk size, so the number of distinct compilations
     is tiny.  Returned logits cover the whole chunk, ``(1, C, V)``.
     """
-    _, pat, n_sb = _scan_layout(cfg)
+    _, pat, _ = _scan_layout(cfg)
     ps = pools["blocks"]["0"]["k"].shape[2]
     C = tokens.shape[1]
     x = L.embed(params, tokens).astype(cfg.jdtype)
     positions = (past + jnp.arange(C))[None, :]             # (1, C)
-    write_flat = block_table[(past + jnp.arange(C)) // ps] * ps + (
-        past + jnp.arange(C)
-    ) % ps
+    write_page = block_table[(past + jnp.arange(C)) // ps]  # (C,)
+    write_slot = (past + jnp.arange(C)) % ps
     kinds = [layer_kind(cfg, j) for j in range(pat)]
 
-    def layer(p: Params, pool: Dict[str, jax.Array], j: int, x: jax.Array):
+    def layer(p: Params, pool: Dict[str, jax.Array], i, j: int, x: jax.Array):
         h = L.rmsnorm(p["norm1"], x, cfg.norm_eps)
         q, k, v = L._proj_qkv(p["attn"], cfg, h, h)
         q = L.rope(q, positions, cfg.rope_theta)
         k = L.rope(k, positions, cfg.rope_theta)
-        new_pool = _write_kv(pool, write_flat, k[0], v[0])
+        pool = _write_kv(pool, i, write_page, write_slot, k[0], v[0])
         out = ops.paged_prefill_attention(
-            q[0], new_pool["k"], new_pool["v"], block_table, past,
-            k_scales=new_pool.get("k_s"), v_scales=new_pool.get("v_s"),
+            q[0], pool["k"], pool["v"], block_table, past, i,
+            k_scales=pool.get("k_s"), v_scales=pool.get("v_s"),
         )[None]
         x = x + out.reshape(1, C, cfg.n_heads * cfg.hd) @ p["attn"]["wo"]
         x = _apply_ffn(p, cfg, kinds[j], x)
-        return x, new_pool
+        return x, pool
 
-    def body(x, xs):
-        new_blk = {}
-        for j in range(pat):
-            p, pool = xs[str(j)]
-            x, new_blk[str(j)] = layer(p, pool, j, x)
-        return x, new_blk
-
-    xs = {
-        str(j): (params["blocks"][str(j)], pools["blocks"][str(j)])
-        for j in range(pat)
-    }
-    x, new_blocks = jax.lax.scan(body, x, xs)
+    x, pools = _layer_loop(params, cfg, pools, x, layer)
 
     x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
     logits = L.unembed(params, x, cfg.tie_embeddings)
-    return logits, {"blocks": new_blocks}
+    return logits, pools

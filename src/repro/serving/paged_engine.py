@@ -57,6 +57,7 @@ from ..kernels.paged_attention import (
     check_block_table_bounds,
     check_scale_pool_finite,
 )
+from ..kernels.ref import scale_view
 from ..models import init_params
 from ..models.config import ModelConfig
 from ..models.paged import (
@@ -108,11 +109,12 @@ class MigrationTicket:
         Tokens currently materialized in the KV cache (prompt + decoded).
     kv : dict
         ``{layer_pattern_pos: {"k"|"v": ndarray}}`` — per-layer KV of
-        the owned pages, shape ``(n_sb, n_pages, page_size, K, hd)``,
-        gathered to host memory in block-table order.  On int8-KV
-        engines the dict additionally carries the per-page scale pools
-        (``"k_s"``/``"v_s"``, ``(n_sb, n_pages, page_size, K)``
-        float32) — the int8 payload is meaningless without them.
+        the owned pages in the pools' stored layout, shape
+        ``(n_sb, n_pages, page_size, K·hd)``, gathered to host memory
+        in block-table order.  On int8-KV engines the dict additionally
+        carries the per-page scale pools (``"k_s"``/``"v_s"``,
+        ``(n_sb, n_pages, 1, page_size·K)`` float32) — the int8 payload
+        is meaningless without them.
     kv_dtype : str
         Source engine's page storage dtype (``"fp32"`` or ``"int8"``);
         source and destination must agree, else the importer would
@@ -400,8 +402,9 @@ class PagedLLMEngine(LatencyProfileMixin):
         Returns
         -------
         int
-            Per-page bytes (pools are shaped ``(n_sb, P, ...)``; the
-            page axis is axis 1).
+            Per-page bytes (every leaf is shaped ``(n_sb, P, ...)``:
+            K/V ``(n_sb, P, page_size, K·hd)``, scales
+            ``(n_sb, P, 1, page_size·K)``; the page axis is axis 1).
         """
         total = 0
         for pool in self.pools["blocks"].values():
@@ -938,9 +941,10 @@ class PagedLLMEngine(LatencyProfileMixin):
                     # spot-check one layer's scale pools: a NaN/non-positive
                     # scale would multiply *valid* history, not masked slots
                     pool0 = self.pools["blocks"]["0"]
+                    K = self.cfg.n_kv_heads
                     check_scale_pool_finite(
-                        np.asarray(jax.device_get(pool0["k_s"][0])),
-                        np.asarray(jax.device_get(pool0["v_s"][0])),
+                        np.asarray(jax.device_get(scale_view(pool0["k_s"][0], K))),
+                        np.asarray(jax.device_get(scale_view(pool0["v_s"][0], K))),
                         bt, lens, self.page_size,
                     )
                 for row in rows:

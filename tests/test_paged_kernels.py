@@ -20,6 +20,7 @@ from repro.kernels import ref as R
 from repro.kernels.paged_attention import (
     paged_decode_attention,
     paged_prefill_attention,
+    write_scales,
 )
 
 RNG = np.random.default_rng(42)
@@ -31,6 +32,25 @@ Q_TOL = 3e-5
 
 def _rand(shape, dtype):
     return jnp.asarray(RNG.normal(size=shape).astype(np.float32), dtype)
+
+
+def _stack(pages, layer=1, n_layers=2):
+    """Stored pool ``(L, P, ps, K·hd)`` holding ``pages`` at ``layer``.
+
+    The other layers hold noise, so a kernel that reads the wrong
+    layer fails the comparison.
+    """
+    pages = jnp.asarray(pages)
+    other = _rand(pages.shape, jnp.float32).astype(pages.dtype)
+    layers = [pages if i == layer else other for i in range(n_layers)]
+    return jnp.stack([R.stored_pages(x) for x in layers])
+
+
+def _stack_scales(scales, layer=1, n_layers=2):
+    """Stored scale pool ``(L, P, 1, ps·K)`` holding ``scales`` at ``layer``."""
+    other = jnp.full(scales.shape, 7.0, jnp.float32)
+    layers = [scales if i == layer else other for i in range(n_layers)]
+    return jnp.stack([R.stored_scales(x) for x in layers])
 
 
 def _random_tables(B, npp, P):
@@ -48,6 +68,8 @@ def _random_tables(B, npp, P):
         (3, 4, 1, 128, 32, 4),    # MQA, wide heads, big pages
         (2, 16, 8, 64, 16, 6),    # many kv heads
         (4, 6, 2, 64, 8, 5),      # odd head-group/page combo
+        (2, 32, 32, 64, 16, 3),   # stablelm-1.6b: MHA 32 x 64
+        (2, 48, 8, 128, 16, 3),   # internlm2-20b: GQA 48 over 8 x 128
     ],
 )
 def test_paged_decode_matches_oracles(B, H, K, hd, ps, npp, dtype):
@@ -57,8 +79,9 @@ def test_paged_decode_matches_oracles(B, H, K, hd, ps, npp, dtype):
     vp = _rand((P, ps, K, hd), dtype)
     bt = _random_tables(B, npp, P)
     lens = jnp.asarray(RNG.integers(1, npp * ps + 1, size=(B,)), jnp.int32)
-    out = paged_decode_attention(q, kp, vp, bt, lens, interpret=True)
-    ref = R.paged_decode_attention_ref(q, kp, vp, bt, lens)
+    ks, vs = _stack(kp), _stack(vp)
+    out = paged_decode_attention(q, ks, vs, bt, lens, 1, interpret=True)
+    ref = R.paged_decode_attention_ref(q, ks, vs, bt, lens, 1)
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(ref, np.float32),
         atol=TOL[dtype], rtol=TOL[dtype],
@@ -97,10 +120,10 @@ def test_paged_decode_ignores_garbage_pages():
                 kp2[bt[b, j], lo:] = 77.0
                 vp2[bt[b, j], lo:] = -77.0
 
-    args = (jnp.asarray(bt, jnp.int32), jnp.asarray(lens, jnp.int32))
-    o1 = paged_decode_attention(q, jnp.asarray(kp), jnp.asarray(vp), *args,
+    args = (jnp.asarray(bt, jnp.int32), jnp.asarray(lens, jnp.int32), 1)
+    o1 = paged_decode_attention(q, _stack(kp), _stack(vp), *args,
                                 interpret=True)
-    o2 = paged_decode_attention(q, jnp.asarray(kp2), jnp.asarray(vp2), *args,
+    o2 = paged_decode_attention(q, _stack(kp2), _stack(vp2), *args,
                                 interpret=True)
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), atol=1e-6)
 
@@ -114,8 +137,9 @@ def test_ops_dispatch_paged_matches_ref():
     vp = _rand((P, ps, K, hd), jnp.float32)
     bt = _random_tables(B, npp, P)
     lens = jnp.asarray([5, 37], jnp.int32)
-    out = ops.paged_decode_attention(q, kp, vp, bt, lens)
-    ref = R.paged_decode_attention_ref(q, kp, vp, bt, lens)
+    ks, vs = _stack(kp), _stack(vp)
+    out = ops.paged_decode_attention(q, ks, vs, bt, lens, 1)
+    ref = R.paged_decode_attention_ref(q, ks, vs, bt, lens, 1)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-6)
 
 
@@ -131,6 +155,8 @@ def test_ops_dispatch_paged_matches_ref():
         (4, 2, 64, 8, 12, 7),     # non-aligned past AND tail
         (4, 1, 128, 32, 5, 3),    # MQA, chunk inside one page
         (6, 2, 64, 8, 17, 23),    # odd everything, multi-page chunk
+        (32, 32, 64, 16, 14, 20),  # stablelm-1.6b: MHA 32 x 64
+        (48, 8, 128, 16, 14, 20),  # internlm2-20b: GQA 48 over 8 x 128
     ],
 )
 def test_paged_prefill_matches_oracles(H, K, hd, ps, past, C, dtype):
@@ -141,8 +167,9 @@ def test_paged_prefill_matches_oracles(H, K, hd, ps, past, C, dtype):
     kp = _rand((P, ps, K, hd), dtype)
     vp = _rand((P, ps, K, hd), dtype)
     bt = _random_tables(1, npp, P)[0]
-    out = paged_prefill_attention(q, kp, vp, bt, past, interpret=True)
-    ref = R.paged_prefill_attention_ref(q, kp, vp, bt, past)
+    ks, vs = _stack(kp), _stack(vp)
+    out = paged_prefill_attention(q, ks, vs, bt, past, 1, interpret=True)
+    ref = R.paged_prefill_attention_ref(q, ks, vs, bt, past, 1)
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(ref, np.float32),
         atol=TOL[dtype], rtol=TOL[dtype],
@@ -193,11 +220,13 @@ def test_paged_prefill_ignores_garbage(quantized):
             kq, ks = R.quantize_kv_ref(kj)
             vq, vs = R.quantize_kv_ref(vj)
             return paged_prefill_attention(
-                q, kq, vq, jnp.asarray(bt, jnp.int32), past,
-                interpret=True, k_scales=ks, v_scales=vs,
+                q, _stack(kq), _stack(vq), jnp.asarray(bt, jnp.int32), past,
+                1, interpret=True, k_scales=_stack_scales(ks),
+                v_scales=_stack_scales(vs),
             )
         return paged_prefill_attention(
-            q, kj, vj, jnp.asarray(bt, jnp.int32), past, interpret=True
+            q, _stack(kj), _stack(vj), jnp.asarray(bt, jnp.int32), past, 1,
+            interpret=True,
         )
 
     np.testing.assert_allclose(
@@ -214,6 +243,8 @@ def test_paged_prefill_ignores_garbage(quantized):
         (2, 8, 2, 64, 16, 8),
         (3, 4, 1, 128, 32, 4),
         (4, 6, 2, 64, 8, 5),
+        (2, 32, 32, 64, 16, 3),   # stablelm-1.6b
+        (2, 48, 8, 128, 16, 3),   # internlm2-20b
     ],
 )
 def test_paged_decode_quantized_matches_oracle(B, H, K, hd, ps, npp):
@@ -223,12 +254,12 @@ def test_paged_decode_quantized_matches_oracle(B, H, K, hd, ps, npp):
     vq, vs = R.quantize_kv_ref(_rand((P, ps, K, hd), jnp.float32))
     bt = _random_tables(B, npp, P)
     lens = jnp.asarray(RNG.integers(1, npp * ps + 1, size=(B,)), jnp.int32)
+    pools = (_stack(kq), _stack(vq))
+    scales = dict(k_scales=_stack_scales(ks), v_scales=_stack_scales(vs))
     out = paged_decode_attention(
-        q, kq, vq, bt, lens, interpret=True, k_scales=ks, v_scales=vs
+        q, *pools, bt, lens, 1, interpret=True, **scales
     )
-    ref = R.paged_decode_attention_ref(
-        q, kq, vq, bt, lens, k_scales=ks, v_scales=vs
-    )
+    ref = R.paged_decode_attention_ref(q, *pools, bt, lens, 1, **scales)
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(ref), atol=Q_TOL, rtol=Q_TOL
     )
@@ -244,9 +275,7 @@ def test_paged_decode_quantized_matches_oracle(B, H, K, hd, ps, npp):
     )
 
 
-@pytest.mark.parametrize("past,C", [(0, 16), (12, 7), (17, 23)])
-def test_paged_prefill_quantized_matches_oracle(past, C):
-    H, K, hd, ps = 4, 2, 64, 8
+def _check_prefill_quantized(H, K, hd, ps, past, C):
     ctx = past + C
     npp = -(-ctx // ps) + 1
     P = npp + 3
@@ -254,15 +283,56 @@ def test_paged_prefill_quantized_matches_oracle(past, C):
     kq, ks = R.quantize_kv_ref(_rand((P, ps, K, hd), jnp.float32))
     vq, vs = R.quantize_kv_ref(_rand((P, ps, K, hd), jnp.float32))
     bt = _random_tables(1, npp, P)[0]
+    pools = (_stack(kq), _stack(vq))
+    scales = dict(k_scales=_stack_scales(ks), v_scales=_stack_scales(vs))
     out = paged_prefill_attention(
-        q, kq, vq, bt, past, interpret=True, k_scales=ks, v_scales=vs
+        q, *pools, bt, past, 1, interpret=True, **scales
     )
-    ref = R.paged_prefill_attention_ref(
-        q, kq, vq, bt, past, k_scales=ks, v_scales=vs
-    )
+    ref = R.paged_prefill_attention_ref(q, *pools, bt, past, 1, **scales)
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(ref), atol=Q_TOL, rtol=Q_TOL
     )
+
+
+@pytest.mark.parametrize("past,C", [(0, 16), (12, 7), (17, 23)])
+def test_paged_prefill_quantized_matches_oracle(past, C):
+    _check_prefill_quantized(4, 2, 64, 8, past, C)
+
+
+@pytest.mark.parametrize(
+    "H,K,hd",
+    [(32, 32, 64),    # stablelm-1.6b: MHA 32 x 64
+     (48, 8, 128)],   # internlm2-20b: GQA 48 over 8 x 128
+)
+def test_paged_prefill_quantized_served_shapes(H, K, hd):
+    _check_prefill_quantized(H, K, hd, 16, 14, 20)
+
+
+@pytest.mark.parametrize(
+    "pages,slots",
+    [
+        ([5, 3, 9], [0, 7, 2]),                  # a decode batch: one per page
+        ([4, 4, 4, 6, 6], [5, 6, 7, 0, 1]),      # a prefill chunk over two pages
+        ([2, 0, 0], [3, 0, 0]),                  # padding rows on the trash page
+    ],
+)
+def test_write_scales_matches_scatter(pages, slots):
+    """The in-place scale writer equals the oracle's scatter, and leaves
+    every other slot, page and layer as it was."""
+    L, P, ps, K = 2, 10, 8, 4
+    pool = jnp.asarray(RNG.uniform(0.5, 2.0, size=(L, P, 1, ps * K)),
+                       jnp.float32)
+    vals = jnp.asarray(RNG.uniform(0.5, 2.0, size=(len(pages), K)),
+                       jnp.float32)
+    pages = jnp.asarray(pages, jnp.int32)
+    slots = jnp.asarray(slots, jnp.int32)
+    want = R.write_scales_ref(pool, 1, pages, slots, vals)
+    got = write_scales(pool, 1, pages, slots, vals, interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    view = np.asarray(R.scale_view(got, K))
+    for t, (p, slot) in enumerate(zip(pages.tolist(), slots.tolist())):
+        if p:   # the trash page's slot is written twice
+            np.testing.assert_array_equal(view[1, p, slot], np.asarray(vals[t]))
 
 
 def test_quantize_roundtrip_error_bound():

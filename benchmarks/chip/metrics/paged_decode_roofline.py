@@ -2,11 +2,10 @@
 
 The least time for the kernel's necessary work, the attention FLOPs and
 the K/V bytes of live tokens only (whatever the kernel reads besides),
-at the chip's peaks, over the kernel's device time in the trace.  On
-decode the bytes set the bound.
+as the configuration's block module counts them, at the chip's peaks,
+over the kernel's device time in the trace.  On decode the bytes set
+the bound.
 """
-
-from peaks import attention_work
 
 
 def read(run):
@@ -18,7 +17,7 @@ def read(run):
     flops = bytes_ = 0.0
     for rec in run.steps:
         if rec[0] == "decode":
-            f, b = attention_work(run.cfg, rec[1])
+            f, b = run.block.attention_work(run.spec, rec[1])
             flops += f
             bytes_ += b
     least = max(flops / run.peaks["bf16_flops"], bytes_ / run.peaks["hbm_bytes_per_s"])

@@ -1,11 +1,10 @@
 """Model FLOPs of the decode and prefill programs over their device time.
 
-FLOPs from the configuration and the rows, contexts and chunks recorded
-around each call in the traced window (padding rows excluded), over the
-device seconds of those programs in the trace times the bf16 peak (%).
+FLOPs from the configuration's block module and the rows, contexts and
+chunks recorded around each call in the traced window (padding rows
+excluded), over the device seconds of those programs in the trace times
+the bf16 peak (%).
 """
-
-from peaks import decode_step_flops, prefill_chunk_flops
 
 
 def read(run):
@@ -17,7 +16,7 @@ def read(run):
     flops = 0.0
     for rec in run.steps:
         if rec[0] == "decode":
-            flops += decode_step_flops(run.cfg, rec[1])
+            flops += run.block.decode_step_flops(run.spec, rec[1])
         else:
-            flops += prefill_chunk_flops(run.cfg, rec[1], rec[2])
+            flops += run.block.prefill_chunk_flops(run.spec, rec[1], rec[2])
     return 100.0 * flops / (t * run.peaks["bf16_flops"])

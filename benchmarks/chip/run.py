@@ -177,12 +177,15 @@ def _new_samples(engines, lat0):
     return secs, rows
 
 
-def run_record(rec, kind: str, seconds: float, setup_s: float, cfg, peaks,
-               steps) -> SimpleNamespace:
+def run_record(rec, kind: str, seconds: float, setup_s: float, peaks, steps,
+               spec=None, block=None) -> SimpleNamespace:
     """What every metric reader reads: the host record and the traced
-    steps; ``trace`` is set once a trace has been reduced."""
-    return SimpleNamespace(kind=kind, seconds=seconds, setup_s=setup_s, cfg=cfg,
-                           peaks=peaks, steps=steps, trace=None, **vars(rec))
+    steps; ``trace`` is set once a trace has been reduced.  ``block``
+    (the configuration's block module) counts the steps' work from
+    ``spec`` (its configuration file)."""
+    return SimpleNamespace(kind=kind, seconds=seconds, setup_s=setup_s, peaks=peaks,
+                           steps=steps, trace=None, spec=spec, block=block,
+                           **vars(rec))
 
 
 def read_metrics(entries, run) -> dict:
@@ -210,13 +213,17 @@ def sample_requests(win, seed: int):
     return [(list(r.prompt), list(r.out_tokens)) for r in chosen]
 
 
-def run_cell(opts, spec, traffic, limits, devices, compiles=None, on_engines=None):
-    """Serve one window and check it.  Returns the result dictionary."""
+def run_cell(opts, spec, block, traffic, limits, devices, compiles=None,
+             on_engines=None):
+    """Serve one window and check it.  Returns the result dictionary.
+
+    ``block`` is the configuration's block module (``H.load_block``)."""
     import jax
 
     kind = traffic["window"]
     jobs, n = T.make_jobs(traffic, opts.seed, opts.seconds)
-    serve, args, engines, weights = H.build_fleet(spec, traffic, opts.seed, devices)
+    serve, args, engines, weights = H.build_fleet(spec, traffic, opts.seed, devices,
+                                                  block)
     if on_engines is not None:
         on_engines(engines)
     trace_dir = None
@@ -240,9 +247,8 @@ def run_cell(opts, spec, traffic, limits, devices, compiles=None, on_engines=Non
     rec = measure(win, jobs, engines, lat0, skipped0)
     mem = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0) for d in devices)
 
-    cfg = H.model_config(spec)
-    run = run_record(rec, kind, float(opts.seconds), setup_s, cfg, opts.peaks,
-                     win.steps)
+    run = run_record(rec, kind, float(opts.seconds), setup_s, opts.peaks, win.steps,
+                     spec=spec, block=block)
     red = None
     if trace_dir is not None:
         import trace_reduce
@@ -262,7 +268,7 @@ def run_cell(opts, spec, traffic, limits, devices, compiles=None, on_engines=Non
     jax.clear_caches()
     t_ref = time.perf_counter()
     control = bool(getattr(opts, "control", False))
-    gaps = R.logit_gaps(weights, cfg, seqs, spec["fleet"]["max_len"],
+    gaps = R.logit_gaps(weights, spec, block, seqs, spec["fleet"]["max_len"],
                         control=control)
     t_ref = time.perf_counter() - t_ref
 
@@ -328,6 +334,7 @@ def main(argv=None) -> int:
         spec = H.load_config(cell["config"])
         traffic = H.load_traffic(cell["traffic"])
         limits = H.load_limits(opts.workload)
+        block = H.load_block(spec)
     except (H.BenchError, json.JSONDecodeError) as e:
         print(f"run.py: {e}", file=sys.stderr)
         return EXIT_ERROR
@@ -353,7 +360,11 @@ def main(argv=None) -> int:
     enable_cache(jax)
     compiles = Compiles()
     jax.monitoring.register_event_duration_secs_listener(compiles)
-    out = run_cell(opts, spec, traffic, limits, devices, compiles)
+    try:
+        out = run_cell(opts, spec, block, traffic, limits, devices, compiles)
+    except H.BenchError as e:
+        print(f"run.py: {e}", file=sys.stderr)
+        return EXIT_ERROR
     for name, c in out["checks"].items():
         print(f"check {name}: {c['value']} (limit {c['rule']} {c['limit']})",
               file=sys.stderr)

@@ -50,8 +50,8 @@ def main(argv=None) -> int:
     if devices is None:
         return RUN.EXIT_NO_CHIP
     RUN.enable_cache(jax)
-    serve, args, engines, _ = H.build_fleet(spec, traffic, opts.seed, devices)
-    cfg = H.model_config(spec)
+    block = H.load_block(spec)
+    serve, args, engines, _ = H.build_fleet(spec, traffic, opts.seed, devices, block)
     for rate in (float(r) for r in opts.rates.split(",")):
         jobs, n = T.make_jobs(traffic, opts.seed, opts.seconds, rate=rate)
         win = W.Window(seconds=opts.seconds, kind="steady")
@@ -65,7 +65,8 @@ def main(argv=None) -> int:
         finally:
             restore()
         rec = RUN.measure(win, jobs, engines, lat0, skipped0)
-        run = RUN.run_record(rec, "steady", opts.seconds, 0.0, cfg, None, [])
+        run = RUN.run_record(rec, "steady", opts.seconds, 0.0, None, [],
+                             spec=spec, block=block)
         got = RUN.read_metrics(READ, run)
         print(json.dumps({
             "rate_jobs_per_s": rate, "jobs": n, "failed": rec.failed,

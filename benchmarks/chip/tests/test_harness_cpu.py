@@ -49,8 +49,14 @@ def smoke_traffic(kind="batch"):
     return traffic
 
 
-def run_smoke(seed=123, fault=None, control=False, limit=None, kind="batch"):
+def run_smoke(seed=123, fault=None, control=False, limit=None, kind="batch",
+              spec=None, block=None):
+    """One window of the cell at smoke size; ``spec`` defaults to the
+    stablelm smoke configuration, ``block`` to the one it names."""
     import jax
+
+    spec = spec or smoke_spec()
+    block = block or H.load_block(spec)
 
     bench = H.load_benchmark()
     limits = {"max_logit_gap": {"limit": limit if limit is not None
@@ -59,7 +65,7 @@ def run_smoke(seed=123, fault=None, control=False, limit=None, kind="batch"):
                            control=control, keep_trace=False, bench=bench,
                            peaks={"bf16_flops": 1.0, "hbm_bytes_per_s": 1.0})
     RUN.T_PROC = __import__("time").perf_counter()
-    return RUN.run_cell(opts, smoke_spec(), smoke_traffic(kind), limits,
+    return RUN.run_cell(opts, spec, block, smoke_traffic(kind), limits,
                         jax.devices()[:1], on_engines=fault)
 
 
@@ -111,7 +117,7 @@ def test_steady_window_reads_job_times():
         "jcts": [1.0], "failed": 0, "ttft_s": [0.5], "lat_samples": [],
         "decode_rows": [], "prefill_run": 0, "prefill_skipped": 0,
         "out_tokens": 0, "sched_s": 0.0, "t_close": 1.0}),
-        "steady", 4.0, 0.0, None, None, [])
+        "steady", 4.0, 0.0, None, [])
     assert H.load_reader("jct_mean_s")(run) == 1.0
     assert H.load_reader("ttft_p95_ms")(run) == 500.0
     assert out["failed"] == 0

@@ -1,4 +1,4 @@
-"""Architecture registry: the 10 assigned configs + reduced smoke variants.
+"""Architecture registry: the assigned configs + reduced smoke variants.
 
 ``get_config(name)`` returns the exact published config;
 ``get_smoke_config(name)`` returns a same-family reduced config that runs
@@ -23,6 +23,7 @@ ARCH_IDS: List[str] = [
     "kimi_k2_1t_a32b",
     "deepseek_v2_lite_16b",
     "xlstm_350m",
+    "trinity_mini",
 ]
 
 # CLI aliases (assignment spelling -> module name)
@@ -37,6 +38,7 @@ ALIASES: Dict[str, str] = {
     "kimi-k2-1t-a32b": "kimi_k2_1t_a32b",
     "deepseek-v2-lite-16b": "deepseek_v2_lite_16b",
     "xlstm-350m": "xlstm_350m",
+    "trinity-mini": "trinity_mini",
 }
 
 

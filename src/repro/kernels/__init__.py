@@ -7,8 +7,9 @@ Each kernel ships three files per the repo contract:
 
 Kernels: flash_attention (prefill/train), decode_attention (serving decode
 hot spot, slot caches), paged_attention (serving decode over paged KV
-pools with block tables), rmsnorm (fused norm), ssm_scan (Mamba selective
-scan).
+pools with block tables, with an optional sliding window), expert_ffn
+(grouped SwiGLU over the experts a device holds), rmsnorm (fused norm),
+ssm_scan (Mamba selective scan).
 """
 
 from . import ops, ref

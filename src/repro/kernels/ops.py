@@ -29,6 +29,13 @@ def _pick(impl: str) -> str:
     return _default_impl() if impl == "auto" else impl
 
 
+def _no_window_kernel(impl: str, name: str) -> None:
+    if _pick(impl) == "pallas":
+        raise NotImplementedError(
+            f"ops.{name}: the Pallas kernel has no sliding window; a windowed "
+            "model runs on the TPU through the paged kernels")
+
+
 # ---------------------------------------------------------------------------
 def attention(
     q: jax.Array,
@@ -39,8 +46,20 @@ def attention(
     q_offset: int = 0,
     kv_len: Optional[jax.Array] = None,
     impl: str = "auto",
+    window: Optional[int] = None,
 ) -> jax.Array:
-    """Multi-head (GQA) attention — flash kernel on TPU, oracle elsewhere."""
+    """Multi-head (GQA) attention — flash kernel on TPU, oracle elsewhere.
+
+    The flash kernel has no sliding ``window``: with one, ``pallas``
+    raises and the oracle serves the CPU (the served path's windows are
+    in the paged kernels).
+    """
+    if window is not None:
+        _no_window_kernel(impl, "attention")
+        return _ref.attention_ref(
+            q, k, v, causal=causal, scale=scale, q_offset=q_offset,
+            kv_len=kv_len, window=window,
+        )
     if _pick(impl) == "pallas":
         from .flash_attention import flash_attention
 
@@ -59,7 +78,14 @@ def decode_attention(
     lengths: jax.Array,
     scale: Optional[float] = None,
     impl: str = "auto",
+    window: Optional[int] = None,
 ) -> jax.Array:
+    """Slot-cache decode attention; a ``window`` as in :func:`attention`."""
+    if window is not None:
+        _no_window_kernel(impl, "decode_attention")
+        return _ref.decode_attention_ref(
+            q, k_cache, v_cache, lengths, scale=scale, window=window
+        )
     if _pick(impl) == "pallas":
         from .decode_attention import decode_attention as _da
 
@@ -78,24 +104,26 @@ def paged_decode_attention(
     k_scales: Optional[jax.Array] = None,
     v_scales: Optional[jax.Array] = None,
     impl: str = "auto",
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Decode attention over layer ``layer`` of stacked paged KV pools.
 
     Pools are stored ``(L, P, page_size, K·hd)`` (see
     :mod:`repro.kernels.paged_attention`).  With ``k_scales``/``v_scales``
     the pools are int8 and dequantized per page inside the kernel
-    (oracle: dequantize-then-attend).
+    (oracle: dequantize-then-attend).  ``window`` (static) limits each
+    query to the last ``window`` positions.
     """
     if _pick(impl) == "pallas":
         from .paged_attention import paged_decode_attention as _pda
 
         return _pda(
             q, k_pages, v_pages, block_tables, lengths, layer, scale=scale,
-            k_scales=k_scales, v_scales=v_scales,
+            k_scales=k_scales, v_scales=v_scales, window=window,
         )
     return _ref.paged_decode_attention_ref(
         q, k_pages, v_pages, block_tables, lengths, layer, scale=scale,
-        k_scales=k_scales, v_scales=v_scales,
+        k_scales=k_scales, v_scales=v_scales, window=window,
     )
 
 
@@ -110,24 +138,55 @@ def paged_prefill_attention(
     k_scales: Optional[jax.Array] = None,
     v_scales: Optional[jax.Array] = None,
     impl: str = "auto",
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Fused chunked-prefill attention over one request's block table.
 
     The chunk's K/V must already be scattered into layer ``layer`` of
     the stacked pools; queries attend causally to paged history + the
     in-chunk segment.  With ``k_scales``/``v_scales`` the pools are int8
-    (see decode).
+    (see decode); ``window`` as in decode.
     """
     if _pick(impl) == "pallas":
         from .paged_attention import paged_prefill_attention as _ppa
 
         return _ppa(
             q, k_pages, v_pages, block_table, past, layer, scale=scale,
-            k_scales=k_scales, v_scales=v_scales,
+            k_scales=k_scales, v_scales=v_scales, window=window,
         )
     return _ref.paged_prefill_attention_ref(
         q, k_pages, v_pages, block_table, past, layer, scale=scale,
-        k_scales=k_scales, v_scales=v_scales,
+        k_scales=k_scales, v_scales=v_scales, window=window,
+    )
+
+
+def expert_ffn(
+    x: jax.Array,
+    w_gate: jax.Array,
+    w_up: jax.Array,
+    w_down: jax.Array,
+    tile_start: jax.Array,
+    tile_count: jax.Array,
+    max_tiles: int,
+    layer=0,
+    impl: str = "auto",
+) -> jax.Array:
+    """Grouped SwiGLU over held experts; rows tile-aligned by expert.
+
+    The weights are stacked ``(L, E_h, ...)`` and ``layer`` picks one.
+    See :mod:`repro.kernels.expert_ffn`; the oracle runs every expert
+    on every row and keeps each row's own.
+    """
+    if _pick(impl) == "pallas":
+        from .expert_ffn import expert_ffn as _ef
+
+        return _ef(x, w_gate, w_up, w_down, tile_start, tile_count, max_tiles,
+                   layer)
+    from .expert_ffn import ROW_TILE
+
+    return _ref.expert_ffn_ref(
+        x, w_gate[layer], w_up[layer], w_down[layer], tile_start, tile_count,
+        ROW_TILE,
     )
 
 

@@ -144,14 +144,20 @@ def _attend_group(
     scale: float,
     heads: int,
     n_kv_heads: int,
+    window: Optional[int] = None,
 ):
-    """Fold one page into one lane group's running softmax."""
+    """Fold one page into one lane group's running softmax.
+
+    With ``window``, a query at ``p`` sees only keys ``t > p - window``.
+    """
     rows = q_ref.shape[0] // heads
     q = q_ref[...].astype(jnp.float32) * scale
     k = k.astype(jnp.float32)
     v = v.astype(jnp.float32)
     kpos = jax.lax.broadcasted_iota(jnp.int32, qpos.shape, 1) + t_start
     mask = kpos <= qpos
+    if window is not None:
+        mask &= kpos > qpos - window
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -221,6 +227,7 @@ def _paged_decode_kernel(
     heads: int,
     n_kv_heads: int,
     quantized: bool,
+    window: Optional[int],
 ):
     if quantized:
         ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
@@ -238,8 +245,13 @@ def _paged_decode_kernel(
     def _():
         _init(m_scr, l_scr, acc_scr)
 
-    # pages wholly beyond this request's history contribute nothing
-    @pl.when(t_start < length)
+    # pages wholly beyond this request's history contribute nothing, nor
+    # (with a window) pages wholly before it, whose index the map clamped
+    live = t_start < length
+    if window is not None:
+        live &= pi >= _first_page(length, window, page_size)
+
+    @pl.when(live)
     def _():
         # the one query sits at position length - 1
         qpos = jnp.full((q_ref.shape[1], page_size), length - 1, jnp.int32)
@@ -252,7 +264,7 @@ def _paged_decode_kernel(
                 m_scr.at[gi], l_scr.at[gi], acc_scr.at[gi],
                 qpos=qpos, t_start=t_start, kv_len=length,
                 first_head=gi * heads, scale=scale, heads=heads,
-                n_kv_heads=n_kv_heads,
+                n_kv_heads=n_kv_heads, window=window,
             )
 
     @pl.when(pi == npp - 1)
@@ -260,7 +272,12 @@ def _paged_decode_kernel(
         _finalize(o_ref, l_scr, acc_scr)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def _first_page(length, window: int, page_size: int):
+    """The first page holding a key the query at ``length - 1`` sees."""
+    return jnp.maximum(length - window, 0) // page_size
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret", "window"))
 def paged_decode_attention(
     q: jax.Array,             # (B, H, hd)
     k_pages: jax.Array,       # (L, P, page_size, K·hd) stored page pools
@@ -272,7 +289,16 @@ def paged_decode_attention(
     interpret: bool = False,
     k_scales: Optional[jax.Array] = None,  # (L, P, 1, page_size·K) f32 (int8)
     v_scales: Optional[jax.Array] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
+    """Decode attention: each row's query attends to its paged history.
+
+    With ``window`` (static), the query at ``lengths - 1`` sees only the
+    last ``window`` positions: keys at or before ``lengths - 1 - window``
+    are masked, and the page index map is clamped from below to the
+    row's first page inside the window, so earlier pages are neither
+    fetched (a repeated block index is not copied again) nor computed.
+    """
     quantized = k_scales is not None
     B, H, hd = q.shape
     page_size = k_pages.shape[2]
@@ -292,12 +318,18 @@ def paged_decode_attention(
         heads=G,
         n_kv_heads=K,
         quantized=quantized,
+        window=window,
     )
 
     import jax.experimental.pallas.tpu as pltpu
 
     # the paged gather: the layer and page come from scalar prefetch
-    page_spec = lambda b, j, bt, lens, lyr: (lyr[0], bt[b, j], 0, 0)  # noqa: E731
+    if window is None:
+        page_spec = lambda b, j, bt, lens, lyr: (lyr[0], bt[b, j], 0, 0)  # noqa: E731
+    else:
+        def page_spec(b, j, bt, lens, lyr):
+            first = _first_page(lens[b], window, page_size)
+            return (lyr[0], bt[b, jnp.maximum(j, first)], 0, 0)
     row_spec = lambda b, j, bt, lens, lyr: (b, 0, 0, 0)  # noqa: E731
     in_specs = [
         pl.BlockSpec((None, n_grp, G * g, G * hd), row_spec),
@@ -346,6 +378,8 @@ def _paged_prefill_kernel(
     heads: int,
     n_kv_heads: int,
     quantized: bool,
+    window: Optional[int],
+    first: int,
 ):
     if quantized:
         ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
@@ -367,9 +401,9 @@ def _paged_prefill_kernel(
     qpos = (r - (r // rows) * rows) // group + past
     _attend_group(
         q_ref, k_ref[...], v_ref[...], ks_ref, vs_ref, m_scr, l_scr, acc_scr,
-        qpos=qpos, t_start=pi * page_size, kv_len=ctx,
+        qpos=qpos, t_start=(pi + first if first else pi) * page_size, kv_len=ctx,
         first_head=gi * heads, scale=scale, heads=heads,
-        n_kv_heads=n_kv_heads,
+        n_kv_heads=n_kv_heads, window=window,
     )
 
     @pl.when(pi == npp - 1)
@@ -378,7 +412,7 @@ def _paged_prefill_kernel(
 
 
 @functools.partial(
-    jax.jit, static_argnames=("past", "scale", "interpret")
+    jax.jit, static_argnames=("past", "scale", "interpret", "window")
 )
 def paged_prefill_attention(
     q: jax.Array,            # (C, H, hd) — one request's chunk queries
@@ -391,12 +425,16 @@ def paged_prefill_attention(
     interpret: bool = False,
     k_scales: Optional[jax.Array] = None,  # (L, P, 1, page_size·K) f32 (int8)
     v_scales: Optional[jax.Array] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Fused chunked-prefill attention over block tables.
 
     The chunk's K/V must already be scattered into the pools (positions
     ``past .. past+C``); its queries attend causally to the
     ``ceil((past+C)/page_size)`` context pages named by the block table.
+    With ``window`` (static), a query at ``p`` sees keys ``t > p -
+    window`` only, and the grid starts at the chunk's first needed page,
+    ``(past - window + 1) // page_size`` (at least 0).
     Returns ``(C, H, hd_v)``.
     """
     quantized = k_scales is not None
@@ -409,6 +447,7 @@ def paged_prefill_attention(
     n_grp = K // G
     ctx = past + C
     n_ctx_pages = -(-ctx // page_size)
+    first = 0 if window is None else max(past - window + 1, 0) // page_size
     scale = scale if scale is not None else hd ** -0.5
 
     # (C, K, g, hd) -> per kv head, C·g rows in token-major order
@@ -424,14 +463,18 @@ def paged_prefill_attention(
         heads=G,
         n_kv_heads=K,
         quantized=quantized,
+        window=window,
+        first=first,
     )
 
     import jax.experimental.pallas.tpu as pltpu
 
     # one grid step: one lane group's columns of one page (a lane-aligned
     # block), so the kernel body holds one group whatever K is
-    group_cols = lambda gi, j, bt, lyr: (lyr[0], bt[j], 0, gi)  # noqa: E731
-    page_row = lambda gi, j, bt, lyr: (lyr[0], bt[j], 0, 0)  # noqa: E731
+    # (a windowed chunk's grid starts at its first needed page)
+    at = (lambda j: j + first) if first else (lambda j: j)  # noqa: E731
+    group_cols = lambda gi, j, bt, lyr: (lyr[0], bt[at(j)], 0, gi)  # noqa: E731
+    page_row = lambda gi, j, bt, lyr: (lyr[0], bt[at(j)], 0, 0)  # noqa: E731
     group_rows = lambda gi, j, bt, lyr: (gi, 0, 0)  # noqa: E731
     in_specs = [
         pl.BlockSpec((None, G * C * g, G * hd), group_rows),
@@ -444,7 +487,7 @@ def paged_prefill_attention(
         operands += _in_hbm(interpret, k_scales, v_scales)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,  # (block_table, layer)
-        grid=(n_grp, n_ctx_pages),
+        grid=(n_grp, n_ctx_pages - first),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((None, G * C * g, G * hd_v), group_rows),
         scratch_shapes=[

@@ -26,6 +26,7 @@ def attention_ref(
     scale: Optional[float] = None,
     q_offset: int = 0,       # absolute position of q[0] (cached prefix len)
     kv_len: Optional[jax.Array] = None,  # (B,) valid kv length per batch
+    window: Optional[int] = None,  # keys more than window-1 back are masked
 ) -> jax.Array:
     B, Sq, H, hd = q.shape
     _, Sk, K, _ = k.shape
@@ -36,10 +37,12 @@ def attention_ref(
     qf = qf.reshape(B, Sq, K, g, hd)
     logits = jnp.einsum("bqkgd,bskd->bkgqs", qf, k.astype(jnp.float32))
     mask = jnp.zeros((B, 1, 1, Sq, Sk), dtype=jnp.float32)
+    qpos = jnp.arange(Sq)[:, None] + q_offset
+    kpos = jnp.arange(Sk)[None, :]
     if causal:
-        qpos = jnp.arange(Sq)[:, None] + q_offset
-        kpos = jnp.arange(Sk)[None, :]
         mask = mask + jnp.where(kpos > qpos, NEG_INF, 0.0)[None, None, None]
+    if window is not None:
+        mask = mask + jnp.where(kpos <= qpos - window, NEG_INF, 0.0)[None, None, None]
     if kv_len is not None:
         valid = jnp.arange(Sk)[None, :] < kv_len[:, None]
         mask = mask + jnp.where(valid, 0.0, NEG_INF)[:, None, None, None, :]
@@ -57,6 +60,7 @@ def decode_attention_ref(
     v_cache: jax.Array,      # (B, S, K, hd)
     lengths: jax.Array,      # (B,) int32 — valid cache entries
     scale: Optional[float] = None,
+    window: Optional[int] = None,  # the query (at lengths-1) sees `window` keys
 ) -> jax.Array:
     B, H, hd = q.shape
     _, S, K, _ = k_cache.shape
@@ -66,6 +70,8 @@ def decode_attention_ref(
     qf = (q.astype(jnp.float32) * scale).reshape(B, K, g, hd)
     logits = jnp.einsum("bkgd,bskd->bkgs", qf, k_cache.astype(jnp.float32))
     valid = jnp.arange(S)[None, :] < lengths[:, None]          # (B, S)
+    if window is not None:
+        valid &= jnp.arange(S)[None, :] >= (lengths - window)[:, None]
     logits = logits + jnp.where(valid, 0.0, NEG_INF)[:, None, None, :]
     w = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bkgs,bskd->bkgd", w, v_cache.astype(jnp.float32))
@@ -137,11 +143,13 @@ def paged_decode_attention_ref(
     scale: Optional[float] = None,
     k_scales: Optional[jax.Array] = None,  # (L, P, 1, page_size·K) f32
     v_scales: Optional[jax.Array] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     K = k_pages.shape[-1] // q.shape[-1]
     k_dense = gather_pages(_layer_pages(k_pages, k_scales, layer, K), block_tables)
     v_dense = gather_pages(_layer_pages(v_pages, v_scales, layer, K), block_tables)
-    return decode_attention_ref(q, k_dense, v_dense, lengths, scale=scale)
+    return decode_attention_ref(q, k_dense, v_dense, lengths, scale=scale,
+                                window=window)
 
 
 # ---------------------------------------------------------------------------
@@ -159,6 +167,7 @@ def paged_prefill_attention_ref(
     scale: Optional[float] = None,
     k_scales: Optional[jax.Array] = None,  # (L, P, 1, page_size·K) f32
     v_scales: Optional[jax.Array] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     K = k_pages.shape[-1] // q.shape[-1]
     C = q.shape[0]
@@ -171,9 +180,67 @@ def paged_prefill_attention_ref(
     kv_len = jnp.array([ctx], jnp.int32)
     out = attention_ref(
         q[None], k_ctx, v_ctx, causal=True, scale=scale,
-        q_offset=past, kv_len=kv_len,
+        q_offset=past, kv_len=kv_len, window=window,
     )
     return out[0]
+
+
+# ---------------------------------------------------------------------------
+# Held experts: a device's share of a routed expert layer
+# ---------------------------------------------------------------------------
+def swiglu_ref(x: jax.Array, w_gate, w_up, w_down) -> jax.Array:
+    """One SwiGLU expert, float32 inside, in ``x``'s dtype out."""
+    h = jnp.dot(x, w_gate, preferred_element_type=jnp.float32)
+    u = jnp.dot(x, w_up, preferred_element_type=jnp.float32)
+    a = (jax.nn.silu(h) * u).astype(x.dtype)
+    return jnp.dot(a, w_down, preferred_element_type=jnp.float32).astype(x.dtype)
+
+
+def expert_ffn_ref(
+    x: jax.Array,            # (M, d) rows grouped by expert, tile-aligned
+    w_gate: jax.Array,       # (E_h, d, f) the held experts
+    w_up: jax.Array,         # (E_h, d, f)
+    w_down: jax.Array,       # (E_h, f, d)
+    tile_start: jax.Array,   # (E_h,) int32 first row tile of each expert
+    tile_count: jax.Array,   # (E_h,) int32 row tiles of each expert
+    tile: int,               # rows per tile
+) -> jax.Array:
+    """Grouped SwiGLU: row tile ``t`` runs through expert ``e`` when
+    ``tile_start[e] <= t < tile_start[e] + tile_count[e]``; rows of no
+    expert's tiles come out 0."""
+    t = jnp.arange(x.shape[0]) // tile
+    out = jnp.zeros(x.shape, jnp.float32)
+    for e in range(w_gate.shape[0]):
+        mine = (t >= tile_start[e]) & (t < tile_start[e] + tile_count[e])
+        y = swiglu_ref(x, w_gate[e], w_up[e], w_down[e]).astype(jnp.float32)
+        out = out + jnp.where(mine[:, None], y, 0.0)
+    return out.astype(x.dtype)
+
+
+def held_experts_ref(
+    x: jax.Array,            # (T, d)
+    ids: jax.Array,          # (T, k) routed experts, of all the router's
+    weights: jax.Array,      # (T, k) float32 combine weights
+    w_gate: jax.Array,       # (E_h, d, f) experts first .. first+E_h-1
+    w_up: jax.Array,
+    w_down: jax.Array,
+    first: int = 0,
+) -> jax.Array:
+    """The one-hot oracle of a held-expert layer, float32 out.
+
+    Every held expert runs on every token; a token takes the weighted
+    sum over its routed experts that are held.  Assignments to other
+    experts add nothing.
+    """
+    E_h = w_gate.shape[0]
+    local = ids - first
+    comb = jnp.zeros((x.shape[0], E_h + 1), jnp.float32)
+    slot = jnp.where((local >= 0) & (local < E_h), local, E_h)
+    comb = comb.at[jnp.arange(x.shape[0])[:, None], slot].add(weights)[:, :E_h]
+    h = jnp.einsum("td,edf->tef", x, w_gate)
+    u = jnp.einsum("td,edf->tef", x, w_up)
+    y_e = jnp.einsum("tef,efd->ted", jax.nn.silu(h) * u, w_down)
+    return jnp.einsum("ted,te->td", y_e.astype(jnp.float32), comb)
 
 
 # ---------------------------------------------------------------------------

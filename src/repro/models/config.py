@@ -34,6 +34,21 @@ class MoEConfig:
     shard_groups: int = 0            # >0: token may route to experts on at
                                      # most this many shards (DeepSeek
                                      # node-limited routing analogue)
+    # router ---------------------------------------------------------------
+    score_func: str = "softmax"      # "softmax" | "sigmoid": sigmoid scores,
+                                     # a per-expert bias that acts on the
+                                     # selection only, the chosen scores
+                                     # renormalised (Trinity, DeepSeek-V3)
+    route_scale: float = 1.0         # the combine weights' factor
+    # the experts this device holds: ``n_held`` of the router's
+    # ``n_experts`` from ``first_held`` on (0 = all).  Assignments to the
+    # others add nothing here; what they would add is another device's.
+    n_held: int = 0
+    first_held: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.n_held or self.n_experts
 
 
 @dataclass(frozen=True)
@@ -95,6 +110,18 @@ class ModelConfig:
     # layer pattern --------------------------------------------------------
     attn_period: int = 1        # hybrid: attention every k-th layer (Jamba 8)
     cross_attn_period: int = 0  # vlm: cross-attn layer every k-th (0 = none)
+    window: int = 0             # >0: sliding-window layers see the last
+                                # `window` positions (their own included)
+    full_attn_every: int = 1    # with a window: every k-th layer (the last
+                                # of each k) attends to the full context
+    rope_full: bool = True      # False: full-attention layers get no
+                                # positional encoding (NoPE)
+
+    # block details (Trinity / afmoe) ---------------------------------------
+    qk_norm: bool = False       # per-head RMSNorm of q and k before RoPE
+    attn_gate: bool = False     # attention output times sigmoid(h @ wg)
+    sandwich_norm: bool = False # RMSNorm of each sublayer's output too
+    scale_embed: bool = False   # embeddings times sqrt(d_model) (muP input)
 
     # runtime knobs ---------------------------------------------------------
     dtype: str = "bfloat16"
@@ -126,6 +153,16 @@ class ModelConfig:
         return self.cross_attn_period > 0 and (i % self.cross_attn_period) == (
             self.cross_attn_period - 1
         )
+
+    def layer_window(self, i: int) -> Optional[int]:
+        """The sliding window of layer i, or None for full attention."""
+        if self.window <= 0 or (i % self.full_attn_every) == self.full_attn_every - 1:
+            return None
+        return self.window
+
+    def layer_rope(self, i: int) -> bool:
+        """Whether layer i rotates q and k (NoPE full layers do not)."""
+        return self.rope_full or self.layer_window(i) is not None
 
     def is_moe_layer(self, i: int) -> bool:
         if self.moe is None:
@@ -173,6 +210,10 @@ class ModelConfig:
                         layer_t += self.n_heads * m.v_head_dim * d
                     else:
                         layer_t += d * q + 2 * d * kv + q * d
+                        if self.attn_gate:
+                            layer_t += d * q
+                        if self.qk_norm:
+                            layer_t += 2 * hd
                 elif self.mamba is not None:
                     di = d * self.mamba.expand
                     layer_t += 2 * d * di + di * d
@@ -181,8 +222,11 @@ class ModelConfig:
                 if self.is_moe_layer(i):
                     m = self.moe
                     e = 3 * d * m.d_ff_expert
-                    layer_t += (m.n_experts + m.n_shared) * e + d * m.n_experts
-                    layer_a += (m.top_k + m.n_shared) * e + d * m.n_experts
+                    router = d * m.n_experts
+                    if m.score_func == "sigmoid":
+                        router += m.n_experts          # selection bias
+                    layer_t += (m.held + m.n_shared) * e + router
+                    layer_a += (min(m.top_k, m.held) + m.n_shared) * e + router
                 elif self.moe is not None and i < self.moe.first_dense:
                     ffd = 3 * d * (self.moe.d_ff_dense or self.d_ff)
                     layer_t += ffd
@@ -199,7 +243,11 @@ class ModelConfig:
         return total, active
 
     def kv_bytes_per_token(self) -> int:
-        """Decode-cache bytes per token (per request) — drives Eq. 2 l(b)."""
+        """Decode-cache bytes per token (per request) — drives Eq. 2 l(b).
+
+        Every attention layer keeps every token: sliding-window layers
+        hold their pages under the shared block table like full ones.
+        """
         b = {"bfloat16": 2, "int8": 1, "float32": 4}[self.kv_cache_dtype]
         if self.family == "ssm":
             return 0  # constant-size recurrent state
@@ -208,10 +256,6 @@ class ModelConfig:
         else:
             per_layer = 2 * self.n_kv_heads * self.hd
         n_attn = sum(1 for i in range(self.n_layers) if self.is_attn_layer(i))
-        if self.family == "hybrid":
-            n_attn = sum(
-                1 for i in range(self.n_layers) if self.is_attn_layer(i)
-            )
         return n_attn * per_layer * b
 
     def with_(self, **kw) -> "ModelConfig":

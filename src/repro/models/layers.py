@@ -123,6 +123,11 @@ def init_attention(mk: Maker, cfg: ModelConfig, cross: bool = False) -> None:
         mk.dense("bq", (h * hd,), ("heads",), zeros=True)
         mk.dense("bk", (k * hd,), ("kv_heads",), zeros=True)
         mk.dense("bv", (k * hd,), ("kv_heads",), zeros=True)
+    if cfg.qk_norm and not cross:
+        mk.dense("q_norm", (hd,), (None,), ones=True)
+        mk.dense("k_norm", (hd,), (None,), ones=True)
+    if cfg.attn_gate and not cross:
+        mk.dense("wg", (d, h * hd), ("embed", "heads"))
 
 
 def _proj_qkv(p: Params, cfg: ModelConfig, x: jax.Array, kv_src: jax.Array):
@@ -139,6 +144,49 @@ def _proj_qkv(p: Params, cfg: ModelConfig, x: jax.Array, kv_src: jax.Array):
     return q, kk, v
 
 
+def head_rmsnorm(gamma: jax.Array, x: jax.Array, eps: float) -> jax.Array:
+    """RMSNorm over each head's ``hd`` lanes: x ``(B, S, H, hd)``, gamma ``(hd,)``.
+
+    Computed on the flat ``(B, S, H·hd)`` view, as :func:`rope` is: each
+    head's sum of squares is a 0/1 matmul, so the projection that feeds
+    it stays in its own layout (no per-layer relayout of its weights).
+    """
+    B, S, H, hd = x.shape
+    xf = x.reshape(B, S, H * hd).astype(jnp.float32)
+    heads = (jnp.arange(H * hd)[:, None] // hd == jnp.arange(H)[None, :])
+    heads = heads.astype(jnp.float32)                       # (H·hd, H)
+    ms = jnp.dot(xf * xf, heads, precision=jax.lax.Precision.HIGHEST) / hd
+    r = jnp.dot(jax.lax.rsqrt(ms + eps), heads.T,
+                precision=jax.lax.Precision.HIGHEST)       # (B, S, H·hd)
+    g = jnp.tile(gamma.astype(jnp.float32), H)
+    return (xf * r * g).astype(x.dtype).reshape(x.shape)
+
+
+def project_qkv(p: Params, cfg: ModelConfig, x: jax.Array,
+                positions: Optional[jax.Array] = None):
+    """q, k, v ``(B, S, ·, hd)`` of self-attention: projections, the
+    per-head QK norm when configured, then rotary at ``positions``
+    (none when None: a NoPE layer, or a caller that rotates itself)."""
+    q, k, v = _proj_qkv(p, cfg, x, x)
+    if cfg.qk_norm:
+        q = head_rmsnorm(p["q_norm"], q, cfg.norm_eps)
+        k = head_rmsnorm(p["k_norm"], k, cfg.norm_eps)
+    if positions is not None:
+        q = rope(q, positions, cfg.rope_theta)
+        k = rope(k, positions, cfg.rope_theta)
+    return q, k, v
+
+
+def attention_output(p: Params, cfg: ModelConfig, h: jax.Array,
+                     out: jax.Array) -> jax.Array:
+    """The flat heads' output ``(B, S, H·hd)``, gated by ``sigmoid(h @ wg)``
+    when configured, through ``wo``."""
+    if cfg.attn_gate:
+        gate = jax.nn.sigmoid((h @ p["wg"]).astype(jnp.float32))
+        out = (out.astype(jnp.float32) * gate).astype(out.dtype)
+    return out @ p["wo"]
+
+
 def attention_full(
     p: Params,
     cfg: ModelConfig,
@@ -146,15 +194,13 @@ def attention_full(
     positions: jax.Array,          # (B, S)
     causal: bool = True,
     use_rope: bool = True,
+    window: Optional[int] = None,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """Full-sequence attention (train / prefill).  Returns (out, kv)."""
-    q, k, v = _proj_qkv(p, cfg, x, x)
-    if use_rope:
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
-    out = ops.attention(q, k, v, causal=causal)
+    q, k, v = project_qkv(p, cfg, x, positions if use_rope else None)
+    out = ops.attention(q, k, v, causal=causal, window=window)
     B, S, _ = x.shape
-    out = out.reshape(B, S, cfg.n_heads * cfg.hd) @ p["wo"]
+    out = attention_output(p, cfg, x, out.reshape(B, S, cfg.n_heads * cfg.hd))
     return out, {"k": k, "v": v}
 
 
@@ -166,22 +212,19 @@ def attention_decode(
     cache_v: jax.Array,
     lengths: jax.Array,            # (B,) tokens already in cache
     use_rope: bool = True,
+    window: Optional[int] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """One-token decode; returns (out, new_cache_k, new_cache_v)."""
     B = x.shape[0]
-    q, k, v = _proj_qkv(p, cfg, x, x)
-    if use_rope:
-        pos = lengths[:, None]
-        q = rope(q, pos, cfg.rope_theta)
-        k = rope(k, pos, cfg.rope_theta)
+    q, k, v = project_qkv(p, cfg, x, lengths[:, None] if use_rope else None)
     # write new kv at position `lengths`
     onehot = jax.nn.one_hot(lengths, cache_k.shape[1], dtype=cache_k.dtype)
     cache_k = cache_k + onehot[:, :, None, None] * k.astype(cache_k.dtype)
     cache_v = cache_v + onehot[:, :, None, None] * v.astype(cache_v.dtype)
     out = ops.decode_attention(
-        q[:, 0], cache_k, cache_v, lengths + 1
+        q[:, 0], cache_k, cache_v, lengths + 1, window=window
     )
-    out = out.reshape(B, 1, cfg.n_heads * cfg.hd) @ p["wo"]
+    out = attention_output(p, cfg, x, out.reshape(B, 1, cfg.n_heads * cfg.hd))
     return out, cache_k, cache_v
 
 
@@ -204,6 +247,7 @@ def attention_decode_q8(
     cache: Dict[str, jax.Array],   # {k,v: int8 (B,S,K,hd); k_s,v_s: (B,S,K)}
     lengths: jax.Array,
     use_rope: bool = True,
+    window: Optional[int] = None,
 ):
     """Decode over an int8-quantized KV cache (serving memory optimization).
 
@@ -212,11 +256,7 @@ def attention_decode_q8(
     bf16 copy of the cache materializes on TPU).
     """
     B = x.shape[0]
-    q, k, v = _proj_qkv(p, cfg, x, x)
-    if use_rope:
-        pos = lengths[:, None]
-        q = rope(q, pos, cfg.rope_theta)
-        k = rope(k, pos, cfg.rope_theta)
+    q, k, v = project_qkv(p, cfg, x, lengths[:, None] if use_rope else None)
     kq, ks = _q8_kv(k)     # (B,1,K,hd), (B,1,K)
     vq, vs = _q8_kv(v)
     onehot = jax.nn.one_hot(lengths, cache["k"].shape[1], dtype=jnp.int8)
@@ -229,8 +269,8 @@ def attention_decode_q8(
     new["v_s"] = cache["v_s"] * (1 - oh_f) + oh_f * vs
     k_deq = _dq8_kv(new["k"], new["k_s"], cfg.jdtype)
     v_deq = _dq8_kv(new["v"], new["v_s"], cfg.jdtype)
-    out = ops.decode_attention(q[:, 0], k_deq, v_deq, lengths + 1)
-    out = out.reshape(B, 1, cfg.n_heads * cfg.hd) @ p["wo"]
+    out = ops.decode_attention(q[:, 0], k_deq, v_deq, lengths + 1, window=window)
+    out = attention_output(p, cfg, x, out.reshape(B, 1, cfg.n_heads * cfg.hd))
     return out, new
 
 
@@ -399,6 +439,15 @@ def init_embed(mk: Maker, cfg: ModelConfig) -> None:
 
 def embed(p: Params, tokens: jax.Array) -> jax.Array:
     return jnp.take(p["embedding"], tokens, axis=0)
+
+
+def embed_tokens(p: Params, cfg: ModelConfig, tokens: jax.Array) -> jax.Array:
+    """The residual stream's input: embeddings in the compute dtype,
+    times ``sqrt(d_model)`` with ``scale_embed`` (muP's input scale)."""
+    x = embed(p, tokens)
+    if cfg.scale_embed:
+        x = x.astype(jnp.float32) * math.sqrt(cfg.d_model)
+    return x.astype(cfg.jdtype)
 
 
 def unembed(p: Params, x: jax.Array, tie: bool) -> jax.Array:

@@ -1,9 +1,21 @@
-"""Mixture-of-Experts: dense one-hot oracle + expert-parallel production path.
+"""Mixture-of-Experts: dense one-hot oracle, held-expert layer, and the
+expert-parallel production path.
 
-Two interchangeable implementations (config ``moe.impl``):
+A device may hold a share of the experts (``moe.n_held`` of the router's
+``n_experts``, from ``moe.first_held``): the router still scores all of
+them, and assignments to experts held elsewhere add nothing here — that
+partial result is what goes on to the next layer.
 
-- ``dense``: every expert runs on every token, combined with top-k gate
-  weights.  O(E) compute — smoke tests / correctness oracle only.
+Three implementations (config ``moe.impl``):
+
+- ``dense``: every held expert runs on every token, combined with top-k
+  gate weights.  O(E) compute — smoke tests / correctness oracle only.
+
+- ``held``: one device's share without a mesh.  The assignments to held
+  experts are grouped by expert, each group starting on a row tile, and
+  one grouped SwiGLU kernel (``kernels/expert_ffn.py``) runs each held
+  expert over its own rows only, reading its weights once; the outputs
+  are combined with the gate weights.  No capacity, no token dropped.
 
 - ``ep``: production path under shard_map.
     * tokens  : sharded over (pod, data), replicated over ``model``;
@@ -30,6 +42,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from ..kernels import ops
+from ..kernels.expert_ffn import ROW_TILE
+from ..kernels.ref import held_experts_ref
 from .config import ModelConfig
 from .layers import Maker, Params, mlp
 
@@ -38,41 +53,117 @@ def init_moe(mk: Maker, cfg: ModelConfig) -> None:
     m = cfg.moe
     d = cfg.d_model
     mk.dense("router", (d, m.n_experts), ("embed", "experts"))
-    # expert dim -> data (EP), ffn dim -> model (TP)
-    mk.dense("w_gate", (m.n_experts, d, m.d_ff_expert), ("experts_ep", None, "ff"))
-    mk.dense("w_up", (m.n_experts, d, m.d_ff_expert), ("experts_ep", None, "ff"))
-    mk.dense("w_down", (m.n_experts, m.d_ff_expert, d), ("experts_ep", "ff", None))
+    # expert dim -> data (EP), ffn dim -> model (TP); a device holding a
+    # share of the experts has only theirs
+    mk.dense("w_gate", (m.held, d, m.d_ff_expert), ("experts_ep", None, "ff"))
+    mk.dense("w_up", (m.held, d, m.d_ff_expert), ("experts_ep", None, "ff"))
+    mk.dense("w_down", (m.held, m.d_ff_expert, d), ("experts_ep", "ff", None))
     if m.n_shared > 0:
         sh = mk.sub("shared")
         sh.dense("w_gate", (d, m.n_shared * m.d_ff_expert), ("embed", "ff"))
         sh.dense("w_up", (d, m.n_shared * m.d_ff_expert), ("embed", "ff"))
         sh.dense("w_down", (m.n_shared * m.d_ff_expert, d), ("ff", "embed"))
+    if m.score_func == "sigmoid":
+        mk.dense("router_bias", (m.n_experts,), ("experts",), fan_in=d)
 
 
 def _routing(p: Params, cfg: ModelConfig, x2d: jax.Array):
+    """Top-k experts of each token, of all the router's, and their weights.
+
+    ``softmax``: the top-k probabilities, renormalised.  ``sigmoid``:
+    float32 scores ``s = sigmoid(x @ router)``; the selection takes the
+    top-k of ``s + router_bias`` (the bias acts on selection only) and
+    the weights are the chosen ``s`` over their sum.  Both times
+    ``route_scale``.
+    """
     m = cfg.moe
-    logits = (x2d @ p["router"]).astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    w, ids = jax.lax.top_k(probs, m.top_k)
-    w = w / jnp.clip(w.sum(-1, keepdims=True), 1e-9)
+    if m.score_func == "sigmoid":
+        s = jax.nn.sigmoid(
+            jnp.dot(x2d, p["router"], preferred_element_type=jnp.float32))
+        _, ids = jax.lax.top_k(s + p["router_bias"].astype(jnp.float32), m.top_k)
+        w = jnp.take_along_axis(s, ids, axis=-1)
+        w = w / (w.sum(-1, keepdims=True) + 1e-20)
+    else:
+        logits = (x2d @ p["router"]).astype(jnp.float32)
+        probs = jax.nn.softmax(logits, axis=-1)
+        w, ids = jax.lax.top_k(probs, m.top_k)
+        w = w / jnp.clip(w.sum(-1, keepdims=True), 1e-9)
+    if m.route_scale != 1.0:
+        w = w * m.route_scale
     return w, ids
 
 
 def moe_dense(p: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
-    """Oracle: run all experts on all tokens (tiny configs only)."""
+    """Oracle: run every held expert on all tokens (tiny configs only)."""
     m = cfg.moe
     B, S, d = x.shape
     x2d = x.reshape(-1, d)
     w, ids = _routing(p, cfg, x2d)
-    comb = jnp.zeros((x2d.shape[0], m.n_experts), jnp.float32)
-    comb = comb.at[jnp.arange(x2d.shape[0])[:, None], ids].add(w)
-    h = jnp.einsum("td,edf->tef", x2d, p["w_gate"])
-    u = jnp.einsum("td,edf->tef", x2d, p["w_up"])
-    y_e = jnp.einsum("tef,efd->ted", jax.nn.silu(h) * u, p["w_down"])
-    y = jnp.einsum("ted,te->td", y_e.astype(jnp.float32), comb).astype(x.dtype)
+    y = held_experts_ref(x2d, ids, w, p["w_gate"], p["w_up"], p["w_down"],
+                         first=m.first_held).astype(x.dtype)
     if m.n_shared > 0:
         y = y + mlp(p["shared"], x2d)
     return y.reshape(B, S, d)
+
+
+def moe_held(
+    p: Params, cfg: ModelConfig, x: jax.Array,
+    valid: Optional[jax.Array] = None, layer=None,
+) -> Tuple[jax.Array, jax.Array]:
+    """A device's share of the expert layer, through the grouped kernel.
+
+    Routes each token over all ``n_experts``, keeps the assignments to
+    the held experts (of tokens where ``valid``, shape ``(B, S)``, is
+    true), places each held expert's rows in whole row tiles of its own,
+    runs :func:`~repro.kernels.ops.expert_ffn` and combines with the
+    gate weights; the shared experts are added for every token.  Expert
+    weights stacked over layers (``(L, E_h, ...)``) are read in place at
+    ``layer`` by the kernel; a single layer's are ``(E_h, ...)``.
+
+    Returns ``(y, counts)``: ``counts`` is int32 ``(held + 1,)``, the
+    valid tokens' assignments to each held expert, then to the experts
+    held elsewhere.
+    """
+    m = cfg.moe
+    B, S, d = x.shape
+    x2d = x.reshape(-1, d)
+    T, k = x2d.shape[0], m.top_k
+    experts = [p[n] for n in ("w_gate", "w_up", "w_down")]
+    if experts[0].ndim == 3:
+        experts, layer = [e[None] for e in experts], 0
+    E_h = experts[0].shape[1]
+    w, ids = _routing(p, cfg, x2d)                            # (T, k)
+    local = ids - m.first_held
+    held = (local >= 0) & (local < E_h)
+    ok = jnp.ones((T,), bool) if valid is None else valid.reshape(T)
+    held &= ok[:, None]
+    onehot = (local.reshape(-1)[:, None] == jnp.arange(E_h)[None, :]) \
+        & held.reshape(-1)[:, None]                           # (T·k, E_h)
+    counts = onehot.sum(0).astype(jnp.int32)
+    tiles = (counts + ROW_TILE - 1) // ROW_TILE
+    tile_start = jnp.cumsum(tiles) - tiles
+    # static bounds: an expert takes a token at most once, and all the
+    # groups' padding is under one tile each
+    max_tiles = -(-T // ROW_TILE)
+    n_tiles = min(E_h * max_tiles,
+                  -(-(T * min(k, E_h) + E_h * (ROW_TILE - 1)) // ROW_TILE))
+    M = n_tiles * ROW_TILE
+    # row of each held assignment: its expert's first row + its rank there
+    rank = jnp.cumsum(onehot, axis=0) - 1
+    col = jnp.clip(local.reshape(-1), 0, E_h - 1)
+    rank = jnp.take_along_axis(rank, col[:, None], axis=1)[:, 0]
+    pos = jnp.where(held.reshape(-1), tile_start[col] * ROW_TILE + rank, M)
+    src = jnp.full((M,), T, jnp.int32).at[pos].set(
+        jnp.repeat(jnp.arange(T, dtype=jnp.int32), k), mode="drop")
+    xs = jnp.concatenate([x2d, jnp.zeros((1, d), x2d.dtype)])[src]
+    ys = ops.expert_ffn(xs, *experts, tile_start, tiles, max_tiles, layer)
+    ys = jnp.concatenate([ys, jnp.zeros((1, d), ys.dtype)])[pos]
+    gate = (w * held).reshape(-1, 1)
+    y = (ys.astype(jnp.float32) * gate).reshape(T, k, d).sum(1)
+    if m.n_shared > 0:
+        y = y + mlp(p["shared"], x2d).astype(jnp.float32)
+    absent = ok.sum(dtype=jnp.int32) * k - counts.sum()
+    return y.astype(x.dtype).reshape(B, S, d), jnp.append(counts, absent)
 
 
 # ---------------------------------------------------------------------------

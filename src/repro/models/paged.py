@@ -34,9 +34,17 @@ page bits are therefore a pure function of the tokens they hold, which
 keeps prefix-cache adoption, copy-on-write, and migration
 token-deterministic under int8.
 
-Supported architectures are the pure-attention decoder families (every
-layer ``attn+{mlp,dense_mlp,moe}``, no prefix/cross/MLA/recurrent
-layers and no int8 cache) — checked by :func:`supports_paged`.  The
+Supported architectures are the pure-attention decoders: every layer
+``attn+{mlp,dense_mlp,moe}``, with no cross/MLA/recurrent layer and no
+slot-cache int8 mode — checked by :func:`supports_paged`.  Layers may
+differ: each scan position passes its own static sliding window and
+rotary flag (NoPE full-attention layers) to the kernels, and the
+unscanned leading layers (whole pattern periods that cover the leading
+dense layers) run before the scan over a pool of their own.  Every
+layer keeps every page under the shared block table, windowed or not.
+Held-expert layers count the tokens they route, per held expert and to
+the experts held elsewhere, in ``pools["experts"]``, which the step
+programs carry with the pools.  The
 numerics intentionally match the slot path bit-for-bit under greedy
 decoding: positions past a request's length are masked to an exact
 softmax weight of 0 in both paths, so recycled page garbage can never
@@ -53,26 +61,38 @@ import jax.numpy as jnp
 from ..kernels import ops
 from . import layers as L
 from .config import ModelConfig
-from .transformer import _apply_ffn, _scan_layout, layer_kind
+from .transformer import _post, _scan_layout, ffn_sublayer, layer_kind
 
 Params = Dict[str, Any]
 Pools = Dict[str, Any]
 
 
 def supports_paged(cfg: ModelConfig) -> bool:
-    """True when every layer's mixer is plain GQA attention."""
+    """True when every layer's mixer is plain GQA self-attention.
+
+    Layers may differ in window and rotary, leading layers may be
+    unscanned (their kinds are read one by one), and the feed-forward
+    may be a dense MLP or an expert layer; the slot cache's int8 mode,
+    latent (MLA), recurrent, cross-attention and encoder layers are not
+    paged.
+    """
     if cfg.family not in ("dense", "moe") or cfg.kv_cache_dtype == "int8":
         return False
     if cfg.mla is not None or cfg.mamba is not None or cfg.encoder is not None:
         return False
     n_prefix, pat, n_sb = _scan_layout(cfg)
-    if n_prefix or n_sb == 0:
+    if n_sb == 0:
         return False
-    kinds = [layer_kind(cfg, j).split("+")[0] for j in range(pat)]
+    kinds = [layer_kind(cfg, i).split("+")[0] for i in range(n_prefix + pat)]
     return all(k == "attn" for k in kinds)
 
 
 KV_DTYPES = ("fp32", "int8")
+
+
+def _counts_expert(cfg: ModelConfig) -> bool:
+    """Whether the step programs count routed tokens (held-expert layers)."""
+    return cfg.moe is not None and cfg.moe.impl == "held"
 
 
 def init_paged_pools(
@@ -84,13 +104,20 @@ def init_paged_pools(
     the page axis is axis 1, and a page is ``page_size`` rows of the
     K/V projection's own width (head ``h`` of slot ``t`` in columns
     ``h·hd .. (h+1)·hd`` of row ``t``), the layout the paged kernels
-    read in place and whose default TPU layout is row-major.  With
+    read in place and whose default TPU layout is row-major.  The
+    unscanned leading layers share one more pool of the same form,
+    ``pools["blocks"]["prefix"]``, stacked over those layers.  With
     ``kv_dtype="int8"`` the K/V leaves are int8 and per-page scale pools
     ride alongside: ``{"k_s","v_s": (n_sb, P, 1, page_size·K) float32}``,
     one scale per token slot per kv head, a page's ``(page_size, K)``
     scales flattened slot-major into one row; initialised to a neutral
     scale of 1 (never-written slots dequantize to finite values the
     kernels' masking then discards).
+
+    A model with held experts also gets ``pools["experts"]``: int32
+    ``(held + 1,)``, the tokens the step programs have routed to each
+    held expert and, last, to experts held elsewhere, summed over
+    layers (decode's padding rows excluded).
     """
     if not supports_paged(cfg):
         raise ValueError(
@@ -99,12 +126,12 @@ def init_paged_pools(
         )
     if kv_dtype not in KV_DTYPES:
         raise ValueError(f"kv_dtype must be one of {KV_DTYPES}, got {kv_dtype!r}")
-    _, pat, n_sb = _scan_layout(cfg)
+    n_prefix, pat, n_sb = _scan_layout(cfg)
     K, hd = cfg.n_kv_heads, cfg.hd
-    kv_shape = (n_sb, num_pages, page_size, K * hd)
-    scale_shape = (n_sb, num_pages, 1, page_size * K)
 
-    def one_pool():
+    def one_pool(n):
+        kv_shape = (n, num_pages, page_size, K * hd)
+        scale_shape = (n, num_pages, 1, page_size * K)
         if kv_dtype == "int8":
             return {
                 "k": jnp.zeros(kv_shape, jnp.int8),
@@ -115,7 +142,13 @@ def init_paged_pools(
         return {"k": jnp.zeros(kv_shape, cfg.jdtype),
                 "v": jnp.zeros(kv_shape, cfg.jdtype)}
 
-    return {"blocks": {str(j): one_pool() for j in range(pat)}}
+    blocks = {str(j): one_pool(n_sb) for j in range(pat)}
+    if n_prefix:
+        blocks["prefix"] = one_pool(n_prefix)
+    pools: Pools = {"blocks": blocks}
+    if _counts_expert(cfg):
+        pools["experts"] = jnp.zeros((cfg.moe.held + 1,), jnp.int32)
+    return pools
 
 
 def _write_kv(pool: Dict[str, jax.Array], layer, page, slot, k, v):
@@ -141,27 +174,57 @@ def _write_kv(pool: Dict[str, jax.Array], layer, page, slot, k, v):
 
 
 def _layer_loop(params: Params, cfg: ModelConfig, pools: Pools, x, layer):
-    """Run every superblock with the stacked pools in the loop's carry.
+    """Run every layer with the stacked pools in the loop's carry.
 
-    ``layer(p, pool, i, j, x) -> (x, pool)`` runs pattern position
-    ``j`` of superblock ``i`` (a traced index).  Carrying the pools,
-    rather than scanning them as per-layer slices, lets each layer write
-    its tokens in place and the kernels read by layer index.
+    ``layer(p, pool, i, li, x) -> (x, pool, counts)`` runs absolute
+    layer ``li`` (static: its kind, window and rotary) on layer ``i`` of
+    ``pool`` (a traced superblock index, or the static index of an
+    unscanned leading layer in the prefix pool); ``counts`` is its
+    routed-token count or None.  Carrying the pools, rather than
+    scanning them as per-layer slices, lets each layer write its tokens
+    in place and the kernels read by layer index.  Held experts' weights
+    stay out of the scan too: ``p["moe"]`` gets them stacked over the
+    superblocks, for the grouped kernel to read layer ``i`` in place (a
+    scanned slice would be copied out for the kernel, every layer).
     """
-    _, pat, n_sb = _scan_layout(cfg)
+    n_prefix, pat, n_sb = _scan_layout(cfg)
+    scanned, experts = dict(params["blocks"]), {}
+    if _counts_expert(cfg):
+        names = ("w_gate", "w_up", "w_down")
+        for j, p in scanned.items():
+            moe = dict(p["moe"])
+            experts[j] = {n: moe.pop(n) for n in names}
+            scanned[j] = {**p, "moe": moe}
+    blocks = dict(pools["blocks"])
+    counts = jnp.zeros_like(pools["experts"]) if "experts" in pools else None
+    for i in range(n_prefix):
+        x, blocks["prefix"], c = layer(params[f"prefix_{i}"], blocks["prefix"], i, i, x)
+        if c is not None:
+            counts = counts + c
+    prefix = blocks.pop("prefix", None)
 
     def body(carry, xs):
-        x, blocks = carry
+        x, blocks, counts = carry
         p, i = xs
         blocks = dict(blocks)
         for j in range(pat):
-            x, blocks[str(j)] = layer(p[str(j)], blocks[str(j)], i, j, x)
-        return (x, blocks), None
+            pj = p[str(j)]
+            if experts:
+                pj = {**pj, "moe": {**pj["moe"], **experts[str(j)]}}
+            x, blocks[str(j)], c = layer(pj, blocks[str(j)], i, n_prefix + j, x)
+            if c is not None:
+                counts = counts + c
+        return (x, blocks, counts), None
 
-    (x, blocks), _ = jax.lax.scan(
-        body, (x, pools["blocks"]), (params["blocks"], jnp.arange(n_sb))
+    (x, blocks, counts), _ = jax.lax.scan(
+        body, (x, blocks, counts), (scanned, jnp.arange(n_sb))
     )
-    return x, {"blocks": blocks}
+    if prefix is not None:
+        blocks["prefix"] = prefix
+    out: Pools = {"blocks": blocks}
+    if counts is not None:
+        out["experts"] = pools["experts"] + counts
+    return x, out
 
 
 # ---------------------------------------------------------------------------
@@ -175,31 +238,39 @@ def paged_decode_step(
     block_tables: jax.Array,  # (B, pages_per_seq) int32
     lengths: jax.Array,       # (B,) int32 — tokens already in cache
 ) -> Tuple[jax.Array, Pools]:
-    """One decode step over paged KV; returns (logits (B, V), pools)."""
+    """One decode step over paged KV; returns (logits (B, V), pools).
+
+    Rows of length 0 are padding: they write the trash page, and the
+    routed-token count leaves them out.
+    """
     B = tokens.shape[0]
-    _, pat, _ = _scan_layout(cfg)
     ps = pools["blocks"]["0"]["k"].shape[2]
-    x = L.embed(params, tokens[:, None]).astype(cfg.jdtype)
+    x = L.embed_tokens(params, cfg, tokens[:, None])
 
     rows = jnp.arange(B)
     write_page = block_tables[rows, lengths // ps]          # (B,)
     write_slot = lengths % ps                               # (B,)
-    kinds = [layer_kind(cfg, j) for j in range(pat)]
+    valid = (lengths > 0)[:, None] if _counts_expert(cfg) else None
 
-    def layer(p: Params, pool: Dict[str, jax.Array], i, j: int, x: jax.Array):
+    def layer(p: Params, pool: Dict[str, jax.Array], i, li: int, x: jax.Array):
         h = L.rmsnorm(p["norm1"], x, cfg.norm_eps)
-        q, k, v = L._proj_qkv(p["attn"], cfg, h, h)         # (B,1,·,hd)
-        pos = lengths[:, None]
-        q = L.rope(q, pos, cfg.rope_theta)
-        k = L.rope(k, pos, cfg.rope_theta)
+        q, k, v = L.project_qkv(p["attn"], cfg, h)          # (B,1,·,hd)
+        if cfg.layer_rope(li):
+            pos = lengths[:, None]
+            q = L.rope(q, pos, cfg.rope_theta)
+            k = L.rope(k, pos, cfg.rope_theta)
         pool = _write_kv(pool, i, write_page, write_slot, k[:, 0], v[:, 0])
         out = ops.paged_decode_attention(
             q[:, 0], pool["k"], pool["v"], block_tables, lengths + 1, i,
             k_scales=pool.get("k_s"), v_scales=pool.get("v_s"),
+            window=cfg.layer_window(li),
         )
-        x = x + out.reshape(B, 1, cfg.n_heads * cfg.hd) @ p["attn"]["wo"]
-        x = _apply_ffn(p, cfg, kinds[j], x, decoding=True)
-        return x, pool
+        out = L.attention_output(
+            p["attn"], cfg, h, out.reshape(B, 1, cfg.n_heads * cfg.hd))
+        x = x + _post(p, cfg, "post_norm1", out)
+        x, counts = ffn_sublayer(p, cfg, layer_kind(cfg, li), x, decoding=True,
+                                 valid=valid, layer=i)
+        return x, pool, counts
 
     x, pools = _layer_loop(params, cfg, pools, x, layer)
 
@@ -229,28 +300,28 @@ def paged_prefill_chunk(
     multiples of the chunk size, so the number of distinct compilations
     is tiny.  Returned logits cover the whole chunk, ``(1, C, V)``.
     """
-    _, pat, _ = _scan_layout(cfg)
     ps = pools["blocks"]["0"]["k"].shape[2]
     C = tokens.shape[1]
-    x = L.embed(params, tokens).astype(cfg.jdtype)
+    x = L.embed_tokens(params, cfg, tokens)
     positions = (past + jnp.arange(C))[None, :]             # (1, C)
     write_page = block_table[(past + jnp.arange(C)) // ps]  # (C,)
     write_slot = (past + jnp.arange(C)) % ps
-    kinds = [layer_kind(cfg, j) for j in range(pat)]
 
-    def layer(p: Params, pool: Dict[str, jax.Array], i, j: int, x: jax.Array):
+    def layer(p: Params, pool: Dict[str, jax.Array], i, li: int, x: jax.Array):
         h = L.rmsnorm(p["norm1"], x, cfg.norm_eps)
-        q, k, v = L._proj_qkv(p["attn"], cfg, h, h)
-        q = L.rope(q, positions, cfg.rope_theta)
-        k = L.rope(k, positions, cfg.rope_theta)
+        q, k, v = L.project_qkv(
+            p["attn"], cfg, h, positions if cfg.layer_rope(li) else None)
         pool = _write_kv(pool, i, write_page, write_slot, k[0], v[0])
         out = ops.paged_prefill_attention(
             q[0], pool["k"], pool["v"], block_table, past, i,
             k_scales=pool.get("k_s"), v_scales=pool.get("v_s"),
+            window=cfg.layer_window(li),
         )[None]
-        x = x + out.reshape(1, C, cfg.n_heads * cfg.hd) @ p["attn"]["wo"]
-        x = _apply_ffn(p, cfg, kinds[j], x)
-        return x, pool
+        out = L.attention_output(
+            p["attn"], cfg, h, out.reshape(1, C, cfg.n_heads * cfg.hd))
+        x = x + _post(p, cfg, "post_norm1", out)
+        x, counts = ffn_sublayer(p, cfg, layer_kind(cfg, li), x, layer=i)
+        return x, pool, counts
 
     x, pools = _layer_loop(params, cfg, pools, x, layer)
 

@@ -66,6 +66,8 @@ def superblock_len(cfg: ModelConfig) -> int:
         periods.append(cfg.xlstm.slstm_every)
     if cfg.attn_period > 1:
         periods.append(cfg.attn_period)
+    if cfg.window > 0 and cfg.full_attn_every > 1:
+        periods.append(cfg.full_attn_every)
     if cfg.cross_attn_period > 0:
         periods.append(cfg.cross_attn_period)
     if cfg.moe is not None and cfg.moe.layer_period > 1:
@@ -74,10 +76,15 @@ def superblock_len(cfg: ModelConfig) -> int:
 
 
 def _scan_layout(cfg: ModelConfig) -> Tuple[int, int, int]:
-    """(n_prefix, pattern_len, n_superblocks): prefix layers are unscanned
-    (e.g. DeepSeek's leading dense layer)."""
-    n_prefix = cfg.moe.first_dense if cfg.moe is not None else 0
+    """(n_prefix, pattern_len, n_superblocks): prefix layers are unscanned.
+
+    The prefix is the fewest whole pattern periods that cover the leading
+    dense layers (DeepSeek's one layer; Trinity's two, in a first period
+    of four), so every scan position keeps one layer kind, window and
+    rotary."""
     pat = superblock_len(cfg)
+    first_dense = cfg.moe.first_dense if cfg.moe is not None else 0
+    n_prefix = -(-first_dense // pat) * pat
     rest = cfg.n_layers - n_prefix
     if rest % pat:
         # pattern does not tile the remaining depth: unscanned prefix only
@@ -111,6 +118,10 @@ def _init_layer(mk: L.Maker, cfg: ModelConfig, kind: str) -> None:
         L.init_mlp(mk.sub("mlp"), cfg.d_model, cfg.moe.d_ff_dense or cfg.d_ff)
     elif ffn == "moe":
         M.init_moe(mk.sub("moe"), cfg)
+    if cfg.sandwich_norm:
+        L.init_rmsnorm(mk, "post_norm1", cfg.d_model)
+        if ffn != "none":
+            L.init_rmsnorm(mk, "post_norm2", cfg.d_model)
 
 
 def _init_decoder_layer_for_audio(mk: L.Maker, cfg: ModelConfig) -> None:
@@ -187,17 +198,41 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Tuple[Params, Params]:
 # ---------------------------------------------------------------------------
 # forward building blocks
 # ---------------------------------------------------------------------------
-def _apply_ffn(p: Params, cfg: ModelConfig, kind: str, x: jax.Array,
-               decoding: bool = False) -> jax.Array:
+def _post(p: Params, cfg: ModelConfig, name: str, y: jax.Array) -> jax.Array:
+    """A sublayer's output as the residual takes it: RMSNorm'd with
+    sandwich norms, else as is."""
+    return L.rmsnorm(p[name], y, cfg.norm_eps) if cfg.sandwich_norm else y
+
+
+def ffn_sublayer(p: Params, cfg: ModelConfig, kind: str, x: jax.Array,
+                 decoding: bool = False, valid: Optional[jax.Array] = None,
+                 layer=None):
+    """``x`` plus the feed-forward sublayer; returns ``(x, counts)``.
+
+    ``counts`` is the held-expert layer's routed-token count (see
+    :func:`repro.models.moe.moe_held`; only tokens where ``valid``),
+    else None.  ``layer`` indexes held experts stacked over layers.
+    """
     mixer, ffn = kind.split("+")
     if ffn == "none":
-        return x
+        return x, None
     h = L.rmsnorm(p["norm2"], x, cfg.norm_eps)
+    counts = None
     if ffn in ("mlp", "dense_mlp"):
         if decoding and cfg.decode_mlp == "ws":
-            return x + L.mlp_ws_decode(p["mlp"], cfg, h)
-        return x + L.mlp(p["mlp"], h)
-    return x + M.moe_block(p["moe"], cfg, h)
+            y = L.mlp_ws_decode(p["mlp"], cfg, h)
+        else:
+            y = L.mlp(p["mlp"], h)
+    elif cfg.moe.impl == "held":
+        y, counts = M.moe_held(p["moe"], cfg, h, valid, layer)
+    else:
+        y = M.moe_block(p["moe"], cfg, h)
+    return x + _post(p, cfg, "post_norm2", y), counts
+
+
+def _apply_ffn(p: Params, cfg: ModelConfig, kind: str, x: jax.Array,
+               decoding: bool = False) -> jax.Array:
+    return ffn_sublayer(p, cfg, kind, x, decoding)[0]
 
 
 def _apply_layer_full(
@@ -208,15 +243,20 @@ def _apply_layer_full(
     positions: jax.Array,
     enc_kv=None,
     causal: bool = True,
+    li: int = 0,
 ):
-    """Full-sequence layer; returns (x, cache_contrib)."""
+    """Full-sequence layer ``li`` (its window and rotary); returns
+    (x, cache_contrib)."""
     mixer, _ = kind.split("+")
     h = L.rmsnorm(p["norm1"], x, cfg.norm_eps)
     cache = None
     if mixer == "attn":
-        out, kv = L.attention_full(p["attn"], cfg, h, positions, causal=causal)
+        out, kv = L.attention_full(
+            p["attn"], cfg, h, positions, causal=causal,
+            use_rope=cfg.layer_rope(li), window=cfg.layer_window(li),
+        )
         cache = kv
-        x = x + out
+        x = x + _post(p, cfg, "post_norm1", out)
     elif mixer == "mla":
         out, ckv = L.mla_full(p["attn"], cfg, h, positions)
         cache = ckv
@@ -248,19 +288,22 @@ def _apply_layer_decode(
     cache: Any,
     lengths: jax.Array,
     enc_kv=None,
+    li: int = 0,
 ):
     mixer, _ = kind.split("+")
     h = L.rmsnorm(p["norm1"], x, cfg.norm_eps)
     new_cache = cache
     if mixer == "attn":
+        attn = dict(use_rope=cfg.layer_rope(li), window=cfg.layer_window(li))
         if cfg.kv_cache_dtype == "int8":
-            out, new_cache = L.attention_decode_q8(p["attn"], cfg, h, cache, lengths)
+            out, new_cache = L.attention_decode_q8(
+                p["attn"], cfg, h, cache, lengths, **attn)
         else:
             out, ck, cv = L.attention_decode(
-                p["attn"], cfg, h, cache["k"], cache["v"], lengths
+                p["attn"], cfg, h, cache["k"], cache["v"], lengths, **attn
             )
             new_cache = {"k": ck, "v": cv}
-        x = x + out
+        x = x + _post(p, cfg, "post_norm1", out)
     elif mixer == "mla":
         out, cc, cr = _mla_decode_absorbed(p["attn"], cfg, h, cache, lengths)
         new_cache = {"c_kv": cc, "k_rope": cr}
@@ -364,7 +407,7 @@ def forward(
 ) -> Tuple[jax.Array, Optional[Dict[str, Any]]]:
     """Returns (logits (B,S,V), cache or None)."""
     B, Sq = tokens.shape
-    x = L.embed(params, tokens).astype(cfg.jdtype)
+    x = L.embed_tokens(params, cfg, tokens)
     x = constrain(x, ("batch", None, None))
     positions = jnp.broadcast_to(jnp.arange(Sq)[None], (B, Sq))
 
@@ -381,7 +424,8 @@ def forward(
 
     for i in range(n_prefix):
         kind = layer_kind(cfg, i)
-        x, c = _apply_layer_full(params[f"prefix_{i}"], cfg, kind, x, positions)
+        x, c = _apply_layer_full(params[f"prefix_{i}"], cfg, kind, x, positions,
+                                 li=i)
         if collect_cache and c is not None:
             caches[f"prefix_{i}"] = c
 
@@ -409,7 +453,8 @@ def forward(
                     new_caches[str(j)] = c
                 else:
                     x2, c = _apply_layer_full(
-                        p, cfg, kinds[j], x, positions, enc_kv=kv
+                        p, cfg, kinds[j], x, positions, enc_kv=kv,
+                        li=n_prefix + j,
                     )
                     if c is not None:
                         new_caches[str(j)] = c
@@ -627,7 +672,7 @@ def decode_step(
         tokens = tokens[:, None]
     B = tokens.shape[0]
     lengths = cache["lengths"]
-    x = L.embed(params, tokens).astype(cfg.jdtype)
+    x = L.embed_tokens(params, cfg, tokens)
     x = constrain(x, ("dec_batch", None, None))
     n_prefix, pat, n_sb = _scan_layout(cfg)
     cross = cache.get("__cross", {})
@@ -636,7 +681,8 @@ def decode_step(
     for i in range(n_prefix):
         kind = layer_kind(cfg, i)
         x, c = _apply_layer_decode(
-            params[f"prefix_{i}"], cfg, kind, x, cache.get(f"prefix_{i}"), lengths
+            params[f"prefix_{i}"], cfg, kind, x, cache.get(f"prefix_{i}"), lengths,
+            li=i,
         )
         new_cache[f"prefix_{i}"] = c
 
@@ -655,7 +701,8 @@ def decode_step(
                     x2, nc = _audio_dec_layer_decode(p, cfg, x, c, lengths, kv)
                 else:
                     x2, nc = _apply_layer_decode(
-                        p, cfg, kinds[j], x, c, lengths, enc_kv=kv
+                        p, cfg, kinds[j], x, c, lengths, enc_kv=kv,
+                        li=n_prefix + j,
                     )
                 new_blk[str(j)] = nc
                 x = x2

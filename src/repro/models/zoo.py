@@ -215,6 +215,7 @@ MODEL_TIERS: Dict[str, TierSpec] = {
     "xlstm_350m":           TierSpec(0.06, 0.35, 0.50),
     "stablelm_1_6b":        TierSpec(0.10, 0.45, 0.60),
     "deepseek_v2_lite_16b": TierSpec(0.28, 0.60, 0.75),
+    "trinity_mini":         TierSpec(0.30, 0.61, 0.78),
     "internlm2_20b":        TierSpec(0.35, 0.62, 0.80),
     "llama3_2_vision_90b":  TierSpec(1.20, 0.78, 1.15),
     "qwen1_5_110b":         TierSpec(1.40, 0.80, 1.20),
@@ -237,6 +238,7 @@ _TIER_ALIASES: Dict[str, str] = {
     "kimi-k2-1t-a32b": "kimi_k2_1t_a32b",
     "deepseek-v2-lite-16b": "deepseek_v2_lite_16b",
     "xlstm-350m": "xlstm_350m",
+    "trinity-mini": "trinity_mini",
     # smoke-config names (same family → same tier economics, so CPU
     # testbeds exercise real heterogeneous routing)
     "stablelm-smoke": "stablelm_1_6b",
@@ -249,6 +251,7 @@ _TIER_ALIASES: Dict[str, str] = {
     "kimi-smoke": "kimi_k2_1t_a32b",
     "deepseek-smoke": "deepseek_v2_lite_16b",
     "xlstm-smoke": "xlstm_350m",
+    "trinity-smoke": "trinity_mini",
 }
 
 

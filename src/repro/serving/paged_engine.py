@@ -349,8 +349,11 @@ class PagedLLMEngine(LatencyProfileMixin):
         if self.prefix_index is not None:
             trash = self._put(np.int32(TRASH_PAGE))
             self.pools = {
-                "blocks": self._copy_page_jit(self.pools["blocks"], trash, trash)
+                **self.pools,
+                "blocks": self._copy_page_jit(self.pools["blocks"], trash, trash),
             }
+        if "experts" in self.pools:     # warm-up tokens are no traffic
+            self.pools["experts"] = jnp.zeros_like(self.pools["experts"])
         jax.block_until_ready(self.pools)
 
     # -- admission ----------------------------------------------------------
@@ -444,16 +447,30 @@ class PagedLLMEngine(LatencyProfileMixin):
             ``budget_bytes // page_bytes`` — how many physical pages
             (trash page included) the budget holds.
         """
-        from ..models.transformer import _scan_layout
-
-        _, pat, n_sb = _scan_layout(cfg)
         K, hd = cfg.n_kv_heads, cfg.hd
         if kv_dtype == "int8":
             per_token = K * (hd * 1 + 4) * 2          # int8 k+v, f32 scales
         else:
             per_token = K * hd * jnp.dtype(cfg.jdtype).itemsize * 2
-        per_page = pat * n_sb * page_size * per_token
+        per_page = cfg.n_layers * page_size * per_token
         return int(budget_bytes // per_page)
+
+    def expert_counts(self) -> Optional[np.ndarray]:
+        """Tokens routed per held expert since warm-up, then to the others.
+
+        The step programs keep this count on the device, in the pools
+        they carry (``pools["experts"]``: int32 ``(held + 1,)``, summed
+        over the expert layers, decode's padding rows left out); reading
+        it is the only host sync.  ``None`` for a model without held
+        experts.
+
+        Returns
+        -------
+        numpy.ndarray or None
+            A host copy of the counts.
+        """
+        counts = self.pools.get("experts")
+        return None if counts is None else np.asarray(jax.device_get(counts))
 
     @property
     def reclaimable_token_capacity(self) -> int:
@@ -630,10 +647,11 @@ class PagedLLMEngine(LatencyProfileMixin):
         rebuilds.
         """
         self.pools = {
+            **self.pools,
             "blocks": self._copy_page_jit(
                 self.pools["blocks"], self._put(np.int32(src)),
                 self._put(np.int32(dst)),
-            )
+            ),
         }
         if self._san is not None and self.kv_dtype == "int8":
             self._san.note_scale_copy(src, dst)
@@ -1160,7 +1178,7 @@ class PagedLLMEngine(LatencyProfileMixin):
             }
             for j, pool in self.pools["blocks"].items()
         }
-        self.pools = {"blocks": blocks}
+        self.pools = {**self.pools, "blocks": blocks}
         self.seq_pages[row] = pages
         self.block_tables[row] = TRASH_PAGE
         self.block_tables[row, : len(pages)] = pages
@@ -1203,10 +1221,8 @@ class PagedLLMEngine(LatencyProfileMixin):
         for old, new in mapping.items():
             perm[new] = old
         perm_j = self._put(perm)
-        self.pools = jax.tree.map(
-            lambda pool: pool[:, perm_j], self.pools["blocks"], is_leaf=None
-        )
-        self.pools = {"blocks": self.pools}
+        blocks = jax.tree.map(lambda pool: pool[:, perm_j], self.pools["blocks"])
+        self.pools = {**self.pools, "blocks": blocks}
         for row, pages in self.seq_pages.items():
             self.seq_pages[row] = [mapping.get(p, p) for p in pages]
             self.block_tables[row] = TRASH_PAGE

@@ -11,9 +11,12 @@ the phase of the program the host was in.  ``meta`` becomes the
 event's stats (a request's ``rid``, a replica's id, a chunk's offset).
 Nothing is kept in memory: the trace is the record.
 
-No span name, or program name, may contain ``paged_decode`` or
-``paged_prefill``: the chip benchmark finds the kernels by those
-substrings in the trace.
+No span name, or program name, may contain ``paged_decode``,
+``paged_prefill`` or ``expert_ffn``: the chip benchmark finds the
+kernels by those substrings in the trace.
+
+Counters are read by method, outside any span; :data:`COUNTERS` lists
+them.
 """
 
 from __future__ import annotations
@@ -52,6 +55,16 @@ SPANS = (
     "engine.decode.call",
     "engine.decode.sync",
     "engine.sample",
+)
+
+
+#: Every counter the program keeps, by the method that reads it.
+COUNTERS = (
+    # lookups and hits of the four per-job caches (core/profiler.py)
+    "ProfileStore.cache_counts",
+    # tokens routed to each held expert, then to experts held elsewhere,
+    # counted on the device by the step programs (serving/paged_engine.py)
+    "PagedLLMEngine.expert_counts",
 )
 
 

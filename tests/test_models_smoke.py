@@ -85,7 +85,8 @@ def test_prefill_decode_no_nan(arch):
 
 
 @pytest.mark.parametrize("arch", ["stablelm_1_6b", "deepseek_v2_lite_16b",
-                                  "xlstm_350m", "whisper_tiny"])
+                                  "xlstm_350m", "whisper_tiny",
+                                  "trinity_mini"])
 def test_decode_parity_with_forward(arch):
     """prefill(t[:n]) + decode steps == forward(t) logits (f32 smoke)."""
     cfg = get_smoke_config(arch).with_(dtype="float32")
@@ -125,6 +126,7 @@ def test_full_config_exact_numbers(arch):
         "kimi_k2_1t_a32b": (61, 7168, 64, 8, 2048, 163840),
         "deepseek_v2_lite_16b": (27, 2048, 16, 16, 1408, 102400),
         "xlstm_350m": (24, 1024, 4, 4, 0, 50304),
+        "trinity_mini": (32, 2048, 32, 4, 6144, 200192),
     }[arch]
     got = (cfg.n_layers, cfg.d_model, cfg.n_heads, cfg.n_kv_heads,
            cfg.d_ff, cfg.vocab)
@@ -139,6 +141,7 @@ def test_param_counts_match_published():
         "jamba_1_5_large_398b": (398e9, 0.1), "whisper_tiny": (39e6, 2.0),
         "kimi_k2_1t_a32b": (1.04e12, 0.1),
         "deepseek_v2_lite_16b": (15.7e9, 0.1), "xlstm_350m": (350e6, 0.5),
+        "trinity_mini": (26e9, 0.05),
     }
     for arch, (target, rel) in tol.items():
         total, active = get_config(arch).param_count()

@@ -165,6 +165,7 @@ def test_span_names_in_source_are_the_listed_ones():
     assert len(set(tracing.SPANS)) == len(tracing.SPANS)
     for name in tracing.SPANS:
         assert "paged_decode" not in name and "paged_prefill" not in name
+        assert "expert_ffn" not in name
 
 
 def test_traced_run_shows_every_exercised_span(traced):
